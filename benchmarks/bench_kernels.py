@@ -66,11 +66,12 @@ def bench_kernels() -> list[tuple]:
     from repro.kernels.flash_attention import ops as fa
     q = jnp.asarray(r.normal(size=(4, 256, 8, 64)).astype(np.float32))
     k = jnp.asarray(r.normal(size=(4, 256, 2, 64)).astype(np.float32))
-    dt = _time(lambda: fa.flash_attention(q, k, k, use_kernel=False)
+    dt = _time(lambda: fa.flash_attention(q, k, k, use_kernel=False,
+                                          interpret=False)
                .block_until_ready())
     rows.append(("kernel/flash_attention_ref_b4s256", dt * 1e6, "oracle"))
     dt = _time(lambda: fa.flash_attention(q, k, k, block_q=128,
-                                          block_kv=128)
+                                          block_kv=128, interpret=True)
                .block_until_ready(), n=1)
     rows.append(("kernel/flash_attention_interp_b4s256", dt * 1e6,
                  "pallas-interpret"))
@@ -80,14 +81,16 @@ def bench_kernels() -> list[tuple]:
     docs = jnp.asarray(r.integers(-1, 4096, (16, 2048)).astype(np.int32))
     imps = jnp.asarray((r.random((16, 2048)) * 255).astype(np.float32))
     dt = _time(lambda: isc.saat_accumulate(docs, imps, n_docs=4096,
-                                           rho=1024, use_kernel=False)
+                                           rho=1024, use_kernel=False,
+                                           interpret=False)
                .block_until_ready())
     rows.append(("kernel/impact_scan_ref_16q", dt * 1e6, "oracle"))
 
     # topk
     from repro.kernels.topk import ops as tk
     s = jnp.asarray(r.normal(size=(16, 65536)).astype(np.float32))
-    dt = _time(lambda: tk.topk_select(s, 64, use_kernel=False)[0]
+    dt = _time(lambda: tk.topk_select(s, 64, use_kernel=False,
+                                      interpret=False)[0]
                .block_until_ready())
     rows.append(("kernel/topk_ref_16x64k", dt * 1e6, "oracle"))
 
@@ -95,7 +98,8 @@ def bench_kernels() -> list[tuple]:
     from repro.kernels.embedding_bag import ops as eb
     t = jnp.asarray(r.normal(size=(100_000, 32)).astype(np.float32))
     ids = jnp.asarray(r.integers(-1, 100_000, (1024, 8)).astype(np.int32))
-    dt = _time(lambda: eb.embedding_bag(t, ids, use_kernel=False)
+    dt = _time(lambda: eb.embedding_bag(t, ids, use_kernel=False,
+                                        interpret=False)
                .block_until_ready())
     rows.append(("kernel/embedding_bag_ref_1k", dt * 1e6, "oracle"))
 
@@ -150,9 +154,10 @@ def bench_impact_scan_sweep() -> list[tuple]:
                 kw = dict(n_docs=nd, rho=jnp.asarray(rho),
                           block_p=bp, block_d=bd, seg_bounds=sb)
                 _, cnt = isc.saat_accumulate(ds, im, with_stats=True,
-                                             **kw)
+                                             **kw, interpret=True)
                 cells = int(np.asarray(cnt).sum())
-                dt = _time(lambda kw=kw: isc.saat_accumulate(ds, im, **kw)
+                dt = _time(lambda kw=kw: isc.saat_accumulate(
+                    ds, im, **kw, interpret=True)
                            .block_until_ready(), n=1)
                 rows.append((f"kernel/impact_scan/bp{bp}_bd{bd}_{variant}",
                              dt * 1e6,

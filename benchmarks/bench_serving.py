@@ -457,20 +457,19 @@ def bench_paced_deadlines() -> list[tuple]:
     ]
 
 
-_SHARDED_SCRIPT = textwrap.dedent("""
-    import os, sys, json, time
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=%(n_dev)d")
-    sys.path.insert(0, %(src)r)
-    import numpy as np
+def sharded_vs_single(n_shards: int, n_docs: int, cap: int) -> dict:
+    """One-device vs ``n_shards``-way sharded engine in *this* process,
+    over the first ``n_shards`` devices the platform has: best-of-3
+    queries/s of each, and whether their ranked lists are identical."""
+    import jax
+
     from repro.core import experiment as E
     from repro.distrib.sharding import make_compat_mesh
     from repro.serving import pipeline as sp
 
     sys_ = E.build_system(E.ExperimentConfig(
-        n_docs=%(n_docs)d, vocab=%(n_docs)d * 2, n_queries=256,
-        stream_cap=%(cap)d, pool_depth=1000, gold_depth=200,
-        query_batch=128))
+        n_docs=n_docs, vocab=n_docs * 2, n_queries=256, stream_cap=cap,
+        pool_depth=1000, gold_depth=200, query_batch=128))
 
     def make_server(mesh=None):
         # slack 2.5: the smoke corpus's doc skew puts up to ~0.56*cap of
@@ -481,7 +480,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
                                partition_slack=2.5)
         srv = sp.RetrievalServer(sys_.index, None, cfg, mesh=mesh)
         srv.predict_classes = (
-            lambda qt: np.arange(qt.shape[0]) %% (len(sys_.k_cutoffs) + 1))
+            lambda qt: np.arange(qt.shape[0]) % (len(sys_.k_cutoffs) + 1))
         return srv
 
     def best_qps(server, qt, n=3):
@@ -495,47 +494,69 @@ _SHARDED_SCRIPT = textwrap.dedent("""
 
     qt = sys_.queries.terms[:128]
     single = make_server()
-    sharded = make_server(make_compat_mesh((1, %(n_shards)d),
-                                           ("data", "model")))
+    sharded = make_server(make_compat_mesh(
+        (1, n_shards), ("data", "model"),
+        devices=jax.devices()[:n_shards]))
     a = single.serve_batch(qt)["ranked"]
     b = sharded.serve_batch(qt)["ranked"]
     eng = sharded.engine
-    print(json.dumps({
+    return {
         "single_qps": best_qps(single, qt),
         "sharded_qps": best_qps(sharded, qt),
-        "n_shards": %(n_shards)d,
+        "n_shards": n_shards,
         "bit_identical": bool(np.array_equal(a, b)),
         "stream_cap": int(eng.cfg.stream_cap),
         "shard_stream_cap": int(eng.shard_cap),
         "partition_slack": float(eng.cfg.partition_slack),
-    }))
+        "platform": jax.devices()[0].platform,
+    }
+
+
+#: CPU emulation only: a child process whose XLA flags give the CPU
+#: backend ``n_dev`` devices (the flag must be set before JAX starts)
+_EMULATED_SCRIPT = textwrap.dedent("""
+    import json, os, sys
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=%(n_dev)d")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path[:0] = [%(src)r, %(root)r]
+    from benchmarks.bench_serving import sharded_vs_single
+    print(json.dumps(sharded_vs_single(%(n_dev)d, %(n_docs)d, %(cap)d)))
 """)
 
 
 def bench_sharded_vs_single() -> list[tuple]:
-    """Mesh-sharded engine vs single device, on a forced-host-device mesh.
+    """Mesh-sharded engine vs single device.
 
-    Runs in a subprocess (XLA's forced device count must be set before
-    backend init).  On emulated CPU devices the sharded path pays real
-    collective overhead for no real parallel FLOPs — the number tracks
-    that overhead across PRs; on TPU the same code path is the scaling
-    story.  Also asserts the sharded output is bit-identical.
+    Runs in this process on the devices the platform has (one process
+    per chip: a child could not reach a chip this process holds).  Only
+    where the platform is the CPU and has too few devices does a child
+    process emulate them with forced host devices — there the sharded
+    path pays real collective overhead for no real parallel FLOPs, so
+    the number tracks that overhead across PRs and says nothing about
+    a chip.  Also asserts the sharded output is bit-identical.
     """
+    import jax
+
     n_shards = int(os.environ.get("REPRO_BENCH_SHARDS", "4"))
     smoke = os.environ.get("REPRO_BENCH_SCALE") == "tiny"
-    script = _SHARDED_SCRIPT % dict(
-        n_dev=n_shards,
-        src=os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "src"),
-        n_docs=2000 if smoke else 8000,
-        cap=512 if smoke else 2048,
-        n_shards=n_shards,
-    )
-    r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, timeout=900)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded bench subprocess failed:\n{r.stderr}")
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    size = dict(n_docs=2000 if smoke else 8000,
+                cap=512 if smoke else 2048)
+    devices = jax.devices()
+    if devices[0].platform != "cpu" or len(devices) >= n_shards:
+        n_shards = min(n_shards, len(devices))
+        out = sharded_vs_single(n_shards, **size)
+    else:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        script = _EMULATED_SCRIPT % dict(
+            n_dev=n_shards, src=os.path.join(root, "src"), root=root,
+            **size)
+        r = subprocess.run([sys.executable, "-c", script],
+                           capture_output=True, text=True, timeout=900)
+        if r.returncode != 0:
+            raise RuntimeError(
+                f"sharded bench subprocess failed:\n{r.stderr}")
+        out = json.loads(r.stdout.strip().splitlines()[-1])
     if not out["bit_identical"]:
         raise RuntimeError("sharded engine diverged from single-device")
     ratio = out["sharded_qps"] / out["single_qps"]
@@ -560,7 +581,7 @@ def bench_sharded_vs_single() -> list[tuple]:
     return [
         ("serving/single_device_qps", out["single_qps"], "128q batch"),
         (f"serving/sharded_{n_shards}dev_qps", out["sharded_qps"],
-         "forced host devices, candidates over 'model'"),
+         f"{out['platform']} devices, candidates over 'model'"),
         ("serving/sharded_vs_single_throughput", ratio,
          f"bit_identical={out['bit_identical']} "
          f"shard_stream={scap}/{cap}"),

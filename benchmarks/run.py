@@ -8,6 +8,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import bench_kernels, bench_online, bench_serving, \
         paper_tables, roofline
 

@@ -16,9 +16,9 @@ Correctness contract (the sharded serving engine builds on it):
   sentinel (-inf) columns *before* sharding, so every global id is the
   true row offset — padded ids (>= N) can only surface when k exceeds
   the real candidate count;
-* ties break deterministically toward the **lowest global id**, matching
-  ``jax.lax.top_k``'s lowest-index rule, so the merged ranking is
-  bit-identical to the unsharded oracle.
+* ties break deterministically toward the **lowest global id**, on every
+  backend (``kernels.topk.ref.top_k_lowest_index``), so the merged
+  ranking is bit-identical to the unsharded oracle.
 """
 
 from __future__ import annotations
@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
+
+from repro.kernels.topk.ref import top_k_lowest_index
 
 __all__ = ["sharded_topk", "merge_local_topk", "gather_local_topk",
            "merge_gathered_topk", "require_axis"]
@@ -64,20 +66,20 @@ def merge_gathered_topk(vflat: jnp.ndarray, gflat: jnp.ndarray, k: int):
     """The arithmetic half of ``merge_local_topk``: merge the gathered
     survivors (value desc, global id asc) down to the top-k.
 
-    A single ``lax.top_k`` over the flat values suffices — no lexsort —
+    A lowest-position top-k over the flat values suffices — no lexsort —
     because of how ``gather_local_topk`` lays the survivors out: within a
     shard's block they arrive value-desc with ties id-asc (the per-shard
-    ``top_k``'s lowest-index rule over id-ordered candidates), and the
+    top-k's lowest-index rule over id-ordered candidates), and the
     blocks are concatenated in ascending doc-range order, so every run of
     tied values is already in ascending global id across the whole row.
-    ``top_k``'s lowest-*position* tie rule therefore picks lowest global
-    id, bit-identical to the lexsort merge at a fraction of the cost
+    The lowest-*position* tie rule therefore picks lowest global id,
+    bit-identical to the lexsort merge at a fraction of the cost
     (XLA:CPU sorts are comparator-driven and dominate the merge).
 
     Returns (values (B, k), ids (B, k)), padded with (-inf, -1) in the
     impossible case that fewer than k survivors exist globally."""
     take = min(k, vflat.shape[1])
-    mv, pos = jax.lax.top_k(vflat, take)
+    mv, pos = top_k_lowest_index(vflat, take)
     mg = jnp.take_along_axis(gflat, pos, axis=1)
     if take < k:
         pad = ((0, 0), (0, k - take))
@@ -93,8 +95,8 @@ def merge_local_topk(v: jnp.ndarray, gi: jnp.ndarray, k: int, axis: str):
     top-``kl`` values and *global* candidate ids, shapes (B, kl).  Only
     these survivors cross the interconnect (2 * B * kl * n_shards words).
     Ties break toward the lowest global id — bit-identical to an
-    unsharded ``jax.lax.top_k`` (which prefers the lowest index), because
-    each shard's survivors are already its lowest-id tied prefix.
+    unsharded ``top_k_lowest_index``, because each shard's survivors are
+    already its lowest-id tied prefix.
 
     Composition of ``gather_local_topk`` + ``merge_gathered_topk`` (the
     engine's overlapped serve path calls the halves separately).
@@ -111,7 +113,8 @@ def sharded_topk(mesh: Mesh, scores: jnp.ndarray, k: int,
     """Top-k over (B, N) scores whose N dim is sharded over ``axis``.
 
     Returns (values (B, k), global indices (B, k) int32), bit-identical
-    to ``jax.lax.top_k(scores, k)`` including tie order (lowest id wins).
+    to ``top_k_lowest_index(scores, k)`` including tie order (lowest id
+    wins).
     Collective volume: 2 * B * min(k, width) * n_shards words instead of
     B * N.
     """
@@ -132,7 +135,7 @@ def sharded_topk(mesh: Mesh, scores: jnp.ndarray, k: int,
 
     def local(s):
         # s: (B, width) local block
-        v, i = jax.lax.top_k(s, kl)
+        v, i = top_k_lowest_index(s, kl)
         base = jax.lax.axis_index(axis) * width
         gi = (i + base).astype(jnp.int32)
         return merge_local_topk(v, gi, k, axis)

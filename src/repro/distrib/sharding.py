@@ -29,42 +29,21 @@ __all__ = ["dp_axes", "dp_axis_spec", "stream_shard_spec", "fsdpify",
            "make_compat_mesh", "compat_shard_map"]
 
 
-def make_compat_mesh(axis_shapes, axis_names) -> Mesh:
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and accepts an
-    ``axis_types`` kwarg (and some versions default to Explicit mode, so
-    we pin Auto); older releases (<= 0.4.x) have neither — fall back to
-    the legacy signature, whose mesh axes are Auto by construction.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_compat_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis pinned to ``AxisType.Auto`` (the
+    sharding rules here rely on GSPMD propagation, not explicit-mode
+    axes).  ``devices`` defaults to all of ``jax.devices()``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across JAX versions, replication checking off.
-
-    Newer JAX promotes it to ``jax.shard_map`` with a ``check_vma`` kwarg;
-    0.4.x has ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking off (the serving
+    bodies return per-shard blocks that the checker cannot type)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def dp_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -49,7 +49,7 @@ def _bag_kernel(ids_ref, counts_ref, table_ref, out_ref, *, n_slots: int,
 @functools.partial(jax.jit, static_argnames=("mean", "interpret"))
 def embedding_bag_kernel(table: jnp.ndarray, ids: jnp.ndarray, *,
                          mean: bool = False,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool) -> jnp.ndarray:
     """table: (V, D); ids: (B, L) int32, -1 padded -> (B, D)."""
     bsz, n_slots = ids.shape
     v, d = table.shape

@@ -12,7 +12,7 @@ __all__ = ["embedding_bag"]
 
 def embedding_bag(table: jnp.ndarray, ids: jnp.ndarray, *,
                   combiner: str = "sum", use_kernel: bool = True,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool) -> jnp.ndarray:
     """table (V, D), ids (B, L) -1-padded -> (B, D)."""
     mean = combiner == "mean"
     if use_kernel:

@@ -94,7 +94,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True, window: int | None = None,
                         block_q: int = 128, block_kv: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """q, k, v: (BH, S, hd) -> (BH, S, hd)."""
     bh, s, hd = q.shape
     bq = min(block_q, s)

@@ -20,7 +20,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: int | None = None,
                     block_q: int = 128, block_kv: int = 128,
                     use_kernel: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """q: (B, S, Hq, hd); k, v: (B, S, Hkv, hd) -> (B, S, Hq, hd)."""
     b, s, hq, hd = q.shape
     hkv = k.shape[2]

@@ -6,16 +6,21 @@ document accumulator.  On CPU JASS this is a scalar scatter loop; the TPU
 adaptation (DESIGN.md §3) reformulates the scatter as a *blocked one-hot
 matmul*, which the MXU executes densely:
 
-    grid = (Q, n_doc_blocks, n_posting_blocks)
+    grid = (Q / ROWS, n_doc_blocks, n_posting_blocks)
     acc[q, db] += impacts[q, pb] @ onehot(doc_ids[q, pb] == doc_range(db))
+                  for each of the cell's ROWS = 8 queries q
+
+Every block is (8, block) over plain 2-D (Q, ·) arrays: the chip's
+compiler needs a block's last two dimensions to be multiples of
+(8, 128) or whole, so a cell takes eight query rows, not one.
 
 ρ is a **traced per-query scalar**, delivered to the kernel through
 scalar prefetch (SMEM), so one compiled executable serves every ρ bucket
 — the grid stays the full padded stream length and early termination
-happens per (query, posting-block) grid cell at run time:
+happens per query row of each grid cell at run time:
 
   * ``pl.when(pb * block_p < rho[q])`` skips posting blocks entirely
-    beyond the query's ρ — the anytime knob as a run-time grid skip,
+    beyond the query's ρ — the anytime knob as a run-time skip,
   * a within-block mask kills the ragged tail where ρ cuts mid-block.
 
 Segment metadata makes the dense grid sparse in the doc dimension too:
@@ -31,10 +36,11 @@ are 8-bit quantized, so every partial sum is exact in f32; see
 tests/test_kernels.py).
 
 VMEM at defaults (block_p=512, block_d=2048): onehot tile 512*2048*4B =
-4 MiB + acc tile 8 KiB — double-bufferable in 16 MiB v5e VMEM.  The
-scalar-prefetch operands (ρ and the segment bounds) are tiny int32 arrays
-resident in SMEM before the body runs, which is what lets the skip
-predicates gate the DMA-fed compute without touching VMEM.
+4 MiB + acc tile 8*2048*4B = 64 KiB — fits the 16 MiB v5e VMEM.  The
+scalar-prefetch operands (ρ and the segment bounds, flattened to 1-D)
+are tiny int32 arrays resident in SMEM before the body runs, which is
+what lets the skip predicates gate the DMA-fed compute without touching
+VMEM.
 """
 
 from __future__ import annotations
@@ -46,7 +52,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["impact_scan", "live_cell_count", "posting_blocks"]
+__all__ = ["ROWS", "impact_scan", "live_cell_count", "posting_blocks"]
+
+#: query rows per grid cell — the sublane count of a (8, 128) VMEM tile,
+#: so every block's second-minor dimension is one whole tile
+ROWS = 8
 
 
 def posting_blocks(p: int, block_p: int) -> tuple[int, int]:
@@ -60,42 +70,52 @@ def posting_blocks(p: int, block_p: int) -> tuple[int, int]:
 
 
 def _impact_kernel(rho_ref, seg_lo_ref, seg_hi_ref, docs_ref, imps_ref,
-                   acc_ref, *stats_ref, block_p: int, block_d: int):
-    q = pl.program_id(0)
+                   acc_ref, *stats_ref, block_p: int, block_d: int,
+                   n_p: int, n_d: int, with_stats: bool):
+    g = pl.program_id(0)
     db = pl.program_id(1)
     pb = pl.program_id(2)
 
     @pl.when(pb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        if stats_ref:
+
+    if with_stats:
+        @pl.when((pb == 0) & (db == 0))
+        def _init_stats():
             stats_ref[0][...] = jnp.zeros_like(stats_ref[0])
 
     base = db * block_d
-    # run-time grid sparsity: ρ early termination + segment intersection
-    live = ((pb * block_p < rho_ref[q])
-            & (seg_lo_ref[q, pb] < base + block_d)
-            & (seg_hi_ref[q, pb] >= base))
+    pidx = pb * block_p + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (1, block_p), 1)
+    # ROWS queries share a grid cell; each row keeps its own run-time
+    # skip (ρ early termination + segment intersection)
+    for r in range(ROWS):
+        q = g * ROWS + r
+        rho = rho_ref[q]
+        seg = q * n_p + pb
+        live = ((pb * block_p < rho)
+                & (seg_lo_ref[seg] < base + block_d)
+                & (seg_hi_ref[seg] >= base))
 
-    @pl.when(live)
-    def _body():
-        docs = docs_ref[0]                           # (block_p,) int32
-        imps = imps_ref[0]                           # (block_p,) f32
-        # rho mask: global posting index < rho[q]; padding (-1) dropped
-        pidx = pb * block_p + jax.lax.broadcasted_iota(
-            jnp.int32, (block_p,), 0)
-        keep = (pidx < rho_ref[q]) & (docs >= 0)
-        w = jnp.where(keep, imps, 0.0)
-        # one-hot over this doc tile: (block_p, block_d)
-        onehot = (docs[:, None] - base
-                  == jax.lax.broadcasted_iota(jnp.int32,
-                                              (block_p, block_d), 1))
-        contrib = jax.lax.dot_general(
-            w[None, :], onehot.astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        acc_ref[0] += contrib[0]
-        if stats_ref:
-            stats_ref[0][0, 0] += 1
+        @pl.when(live)
+        def _body():
+            docs = docs_ref[r:r + 1, :]                  # (1, block_p)
+            # rho mask: global posting index < rho[q]; padding (-1) dropped
+            keep = (pidx < rho) & (docs >= 0)
+            w = jnp.where(keep, imps_ref[r:r + 1, :], 0.0)
+            # transposed one-hot over this doc tile: (block_d, block_p),
+            # docs broadcast along sublanes (no lane->sublane relayout)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32,
+                                               (block_d, block_p), 0)
+                      == docs - base)
+            contrib = jax.lax.dot_general(
+                w, onehot.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # (1, block_d)
+            acc_ref[r:r + 1, :] += contrib
+            if with_stats:
+                hit = jax.lax.broadcasted_iota(jnp.int32, (1, n_d), 1) == db
+                stats_ref[0][r:r + 1, :] += hit.astype(jnp.int32)
 
 
 @functools.partial(
@@ -105,13 +125,15 @@ def impact_scan(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
                 rho_vec: jnp.ndarray, seg_lo: jnp.ndarray,
                 seg_hi: jnp.ndarray, *, n_docs: int, block_p: int = 512,
                 block_d: int = 2048, with_stats: bool = False,
-                interpret: bool = True):
+                interpret: bool):
     """Accumulate the first ``rho_vec[q]`` postings of each stream.
 
     doc_stream: (Q, P) int32 (-1 padded), impact_stream: (Q, P) f32, both
     impact-descending.  rho_vec: (Q,) int32 traced per-query ρ.
     seg_lo/seg_hi: (Q, n_posting_blocks) int32 per-block min/max doc id
-    (empty blocks: the empty interval ``(n_docs, -1)``).
+    (empty blocks: the empty interval ``(n_docs, -1)``).  ``interpret``
+    has no default: the caller states whether the body runs compiled
+    (TPU) or in the Pallas interpreter.
 
     Returns (Q, n_docs) accumulators equal to processing exactly the
     first ``rho_vec[q]`` postings of query ``q``; with ``with_stats``
@@ -130,28 +152,38 @@ def impact_scan(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
             f"{block_p} (got {seg_lo.shape} / {seg_hi.shape}); compute "
             "them with retrieval.index.block_doc_bounds at the same "
             "block size")
-    p_pad = n_p * bp
-    if p_pad != p:  # pad the ragged tail so the last block reads real data
-        doc_stream = jnp.pad(doc_stream, ((0, 0), (0, p_pad - p)),
-                             constant_values=-1)
-        impact_stream = jnp.pad(impact_stream, ((0, 0), (0, p_pad - p)),
-                                constant_values=0.0)
     bd = min(block_d, n_docs)
     n_d = -(-n_docs // bd)
-    d_pad = n_d * bd
+    # pad the ragged stream tail so the last block reads real data, and
+    # the query axis to whole ROWS groups (padding rows carry rho 0 and
+    # the empty segment, so they never execute)
+    q_pad = -(-qn // ROWS) * ROWS
+    p_pad = n_p * bp
+    rows, cols = (0, q_pad - qn), (0, p_pad - p)
+    doc_stream = jnp.pad(doc_stream, (rows, cols), constant_values=-1)
+    impact_stream = jnp.pad(impact_stream, (rows, cols),
+                            constant_values=0.0)
+    rho_vec = jnp.pad(rho_vec.astype(jnp.int32), rows)
+    # 1-D scalar-prefetch operands: row q's bounds start at q * n_p
+    seg_lo = jnp.pad(seg_lo.astype(jnp.int32), (rows, (0, 0)),
+                     constant_values=n_docs).reshape(-1)
+    seg_hi = jnp.pad(seg_hi.astype(jnp.int32), (rows, (0, 0)),
+                     constant_values=-1).reshape(-1)
 
-    kernel = functools.partial(_impact_kernel, block_p=bp, block_d=bd)
-    out_specs = [pl.BlockSpec((1, bd), lambda q, d, s, *refs: (q, d))]
-    out_shape = [jax.ShapeDtypeStruct((qn, d_pad), jnp.float32)]
+    kernel = functools.partial(_impact_kernel, block_p=bp, block_d=bd,
+                               n_p=n_p, n_d=n_d, with_stats=with_stats)
+    out_specs = [pl.BlockSpec((ROWS, bd), lambda g, d, s, *refs: (g, d))]
+    out_shape = [jax.ShapeDtypeStruct((q_pad, n_d * bd), jnp.float32)]
     if with_stats:
-        out_specs.append(pl.BlockSpec((1, 1), lambda q, d, s, *refs: (q, d)))
-        out_shape.append(jax.ShapeDtypeStruct((qn, n_d), jnp.int32))
+        out_specs.append(
+            pl.BlockSpec((ROWS, n_d), lambda g, d, s, *refs: (g, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((q_pad, n_d), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,           # rho_vec, seg_lo, seg_hi in SMEM
-        grid=(qn, n_d, n_p),
+        grid=(q_pad // ROWS, n_d, n_p),
         in_specs=[
-            pl.BlockSpec((1, bp), lambda q, d, s, *refs: (q, s)),
-            pl.BlockSpec((1, bp), lambda q, d, s, *refs: (q, s)),
+            pl.BlockSpec((ROWS, bp), lambda g, d, s, *refs: (g, s)),
+            pl.BlockSpec((ROWS, bp), lambda g, d, s, *refs: (g, s)),
         ],
         out_specs=out_specs,
     )
@@ -160,10 +192,9 @@ def impact_scan(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(rho_vec.astype(jnp.int32), seg_lo.astype(jnp.int32),
-      seg_hi.astype(jnp.int32), doc_stream, impact_stream)
-    acc = out[0][:, :n_docs]
-    return (acc, out[1]) if with_stats else acc
+    )(rho_vec, seg_lo, seg_hi, doc_stream, impact_stream)
+    acc = out[0][:qn, :n_docs]
+    return (acc, out[1][:qn]) if with_stats else acc
 
 
 def live_cell_count(rho_vec, seg_lo, seg_hi, *, p: int, n_docs: int,

@@ -67,7 +67,7 @@ def saat_accumulate(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray, *,
                     n_docs: int, rho, use_kernel: bool = True,
                     block_p: int = 512, block_d: int = 2048,
                     seg_bounds=None, with_stats: bool = False,
-                    interpret: bool = True):
+                    interpret: bool):
     """Score-at-a-time accumulation of the first ``rho`` postings.
 
     rho: static int or traced (Q,) integer vector.
@@ -76,6 +76,8 @@ def saat_accumulate(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray, *,
     with_stats: also return the executed-grid-cell counts — the kernel's
     measured counts on the kernel path, the analytically identical
     predicate sum on the oracle path.
+    interpret: no default — the caller states whether the kernel runs
+    compiled (TPU) or in the Pallas interpreter.
     """
     qn, p = doc_stream.shape
     static_rho = None

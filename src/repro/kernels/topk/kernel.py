@@ -9,9 +9,10 @@ Two-stage selection over dense stage-1 scores (DESIGN.md §3):
   (one block may hold up to k of the global top-k; any weaker condition
   — in particular "k >= block size" with k' < block size — silently
   drops candidates).  The kernel supports k' <= KP_MAX = 128, so exact
-  selection wider than 128 must use the oracle path
-  (``ops.topk_select`` falls back automatically); ``block_topk`` itself
-  rejects an out-of-range k' rather than return a wrong pool.
+  selection wider than 128 takes the sort path, a route the serving
+  engine decides once per program (``retrieval.topk.pool_route``);
+  ``block_topk`` itself rejects an out-of-range k' rather than return a
+  wrong pool.
 
   stage 2 (ops.py): a single jnp top_k over the (n_blocks * k') surviving
   candidates — tiny compared to the original score vector.
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["KP_MAX", "block_topk"]
+__all__ = ["KP_MAX", "ROWS", "block_topk"]
 
 NEG_INF = -jnp.inf
 
@@ -41,68 +42,86 @@ NEG_INF = -jnp.inf
 #: beyond this the containment guarantee must come from the oracle path
 KP_MAX = 128
 
+#: query rows per grid cell (one (8, 128) tile's sublanes); each cell
+#: writes one (ROWS, KP_MAX) tile of values and one of indices
+ROWS = 8
+
 
 def _topk_kernel(scores_ref, vals_ref, idxs_ref, *, kp: int, block_n: int):
     bi = pl.program_id(1)
-    s = scores_ref[0].astype(jnp.float32)            # (block_n,)
+    s = scores_ref[...].astype(jnp.float32)          # (ROWS, block_n)
     base = bi * block_n
-    # deterministic ties: prefer lower doc id => subtract tiny rank epsilon
-    local_idx = jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
+    # deterministic ties: prefer lower doc id => lowest-index argmax
+    local_idx = jax.lax.broadcasted_iota(jnp.int32, (ROWS, block_n), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (ROWS, KP_MAX), 1)
 
     def body(j, carry):
-        s_cur, = carry
-        m = jnp.max(s_cur)
+        s_cur, vals, idxs = carry
+        m = jnp.max(s_cur, axis=1, keepdims=True)             # (ROWS, 1)
         # argmax with lowest-index tie-break
-        is_max = s_cur == m
-        amax = jnp.min(jnp.where(is_max, local_idx, block_n))
-        vals_ref[0, j] = m
-        idxs_ref[0, j] = base + amax
+        amax = jnp.min(jnp.where(s_cur == m, local_idx, block_n), axis=1,
+                       keepdims=True)
+        # round j's winner lands in lane j of register-resident outputs:
+        # a full-tile select, not a dynamic single-lane store
+        vals = jnp.where(lane == j, m, vals)
+        idxs = jnp.where(lane == j, base + amax, idxs)
         s_cur = jnp.where(local_idx == amax, NEG_INF, s_cur)
-        return (s_cur,)
+        return s_cur, vals, idxs
 
-    jax.lax.fori_loop(0, kp, body, (s,))
+    init = (s, jnp.full((ROWS, KP_MAX), NEG_INF, jnp.float32),
+            jnp.zeros((ROWS, KP_MAX), jnp.int32))
+    _, vals, idxs = jax.lax.fori_loop(0, kp, body, init)
+    vals_ref[...] = vals
+    idxs_ref[...] = idxs
 
 
 @functools.partial(
     jax.jit, static_argnames=("kp", "block_n", "interpret"))
 def block_topk(scores: jnp.ndarray, *, kp: int, block_n: int = 4096,
-               interpret: bool = True):
+               interpret: bool):
     """scores: (Q, N) -> (vals (Q, n_blocks*kp), idxs (Q, n_blocks*kp)).
 
     Per-block top-kp candidates; the caller merges (ops.topk_select) and
     may only trust the merged global top-k for k <= kp.  kp outside
     [1, KP_MAX] raises — a wider kp breaks the kernel's register-resident
-    extraction budget and callers who need k > KP_MAX must use the
-    oracle, never a silently-wrong block union.
+    extraction budget and callers who need k > KP_MAX must route pool
+    selection to the sort path (``retrieval.topk.pool_route``), never to
+    a silently-wrong block union.  ``interpret`` has no default: the
+    caller states whether the body runs compiled or interpreted.
+
+    Each grid cell takes ROWS queries x one score block and writes a
+    (ROWS, KP_MAX) tile per output; lanes past ``kp`` are sliced off.
     """
     if not 1 <= kp <= KP_MAX:
         raise ValueError(
             f"block_topk kp must be in [1, {KP_MAX}], got {kp}; the "
             "global top-k is only contained in the per-block unions for "
             f"k <= kp, and kp > {KP_MAX} exceeds the kernel's iterative-"
-            "extraction budget — use ops.topk_select (oracle fallback) "
-            "for wider selections")
+            "extraction budget — route wider selections to the sort "
+            "path (retrieval.topk.pool_route)")
     qn, n = scores.shape
     bn = min(block_n, n)
     n_b = -(-n // bn)
-    n_pad = n_b * bn
-    if n_pad != n:
-        scores = jnp.pad(scores, ((0, 0), (0, n_pad - n)),
-                         constant_values=NEG_INF)
+    q_pad = -(-qn // ROWS) * ROWS
+    scores = jnp.pad(scores, ((0, q_pad - qn), (0, n_b * bn - n)),
+                     constant_values=NEG_INF)
 
     kernel = functools.partial(_topk_kernel, kp=kp, block_n=bn)
+    tile = pl.BlockSpec((ROWS, KP_MAX), lambda g, b: (g, b))
     vals, idxs = pl.pallas_call(
         kernel,
-        grid=(qn, n_b),
-        in_specs=[pl.BlockSpec((1, bn), lambda q, b: (q, b))],
-        out_specs=[
-            pl.BlockSpec((1, kp), lambda q, b: (q, b)),
-            pl.BlockSpec((1, kp), lambda q, b: (q, b)),
-        ],
+        grid=(q_pad // ROWS, n_b),
+        in_specs=[pl.BlockSpec((ROWS, bn), lambda g, b: (g, b))],
+        out_specs=[tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((qn, n_b * kp), jnp.float32),
-            jax.ShapeDtypeStruct((qn, n_b * kp), jnp.int32),
+            jax.ShapeDtypeStruct((q_pad, n_b * KP_MAX), jnp.float32),
+            jax.ShapeDtypeStruct((q_pad, n_b * KP_MAX), jnp.int32),
         ],
         interpret=interpret,
     )(scores)
-    return vals, idxs
+
+    def trim(x):
+        return x[:qn].reshape(qn, n_b, KP_MAX)[..., :kp].reshape(
+            qn, n_b * kp)
+
+    return trim(vals), trim(idxs)

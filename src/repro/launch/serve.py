@@ -19,6 +19,11 @@ The warmup policy persists its padded-shape census to ``--census`` on
 the previous run's shape distribution in the background with no explicit
 batch-size list.
 
+The process exits non-zero when any request or any warmup compile
+failed.  JAX's persistent compilation cache is on: where
+``JAX_COMPILATION_CACHE_DIR`` is set it decides, otherwise the cache is
+``<checkout>/.jax_cache`` (``launch/compile_cache.py``).
+
 ``--online`` closes the adaptation loop (src/repro/online): the service
 taps per-request telemetry into a ring buffer, a background shadow
 thread re-runs sampled queries at full fidelity on idle capacity and
@@ -32,6 +37,26 @@ from __future__ import annotations
 import argparse
 
 import numpy as np
+
+
+def build_service(backend, *, batch: int, deadline_ms: float,
+                  census: str = "", telemetry=None, obs=None):
+    """The ``RetrievalService`` this driver serves through, over any
+    backend (``EngineBackend``, ``ShardedEngineBackend`` or the slot
+    scheduler's ``ContinuousBackend``).  ``census`` is the warmup
+    policy's padded-shape census file ('' keeps none)."""
+    from repro.serving.admission import AdmissionConfig
+    from repro.serving.service import RetrievalService, WarmupPolicy
+    return RetrievalService(
+        backend,
+        AdmissionConfig(max_batch=batch,
+                        pad_multiple=backend.pad_multiple,
+                        default_deadline_ms=deadline_ms),
+        # the census reloads the previous run's padded-shape
+        # distribution, so the background thread pre-compiles it at
+        # deploy time; warmup_now covers the first-boot case
+        warmup=WarmupPolicy(census_path=census or None),
+        telemetry=telemetry, obs=obs)
 
 
 def main() -> None:
@@ -72,10 +97,12 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.launch import mesh as mesh_lib
+    from repro.launch.compile_cache import use_compile_cache
     if args.force_host_devices:
         # before anything touches a jax device: the flag only works if
         # the backends have not initialized yet
         mesh_lib.force_host_device_count(args.force_host_devices)
+    use_compile_cache()
 
     from repro.core import cascade as cascade_lib
     from repro.core import experiment as E
@@ -84,9 +111,7 @@ def main() -> None:
     from repro.online import (OnlineConfig, OnlineController,
                               TelemetryBuffer, TrainerConfig)
     from repro.serving import pipeline as sp
-    from repro.serving.admission import AdmissionConfig
-    from repro.serving.service import (EngineBackend, RetrievalService,
-                                       ShardedEngineBackend, WarmupPolicy)
+    from repro.serving.service import EngineBackend, ShardedEngineBackend
 
     mesh = None
     if args.shards > 1 or args.data_shards > 1:
@@ -119,17 +144,10 @@ def main() -> None:
     # an export flag asks for it, so the default path records nothing
     obs = (Observability.create()
            if args.trace_out or args.metrics_snapshot else NULL_OBS)
-    service = RetrievalService(
-        backend,
-        AdmissionConfig(max_batch=args.batch,
-                        pad_multiple=backend.pad_multiple,
-                        default_deadline_ms=args.deadline_ms),
-        # the census reloads the previous run's padded-shape
-        # distribution, so the background thread pre-compiles it at
-        # deploy time; warmup_now covers the first-boot case
-        warmup=WarmupPolicy(census_path=args.census or None),
-        telemetry=TelemetryBuffer() if args.online else None,
-        obs=obs)
+    service = build_service(
+        backend, batch=args.batch, deadline_ms=args.deadline_ms,
+        census=args.census,
+        telemetry=TelemetryBuffer() if args.online else None, obs=obs)
     service.warmup_now([args.batch])       # deploy-time shape; the
     # warmup policy keeps compiling whatever shapes admission produces
 
@@ -200,6 +218,12 @@ def main() -> None:
             args.metrics_snapshot, obs.metrics,
             extra={"argv_knob": args.knob, "batches": args.batches})
         print(f"metrics snapshot -> {args.metrics_snapshot}")
+    # a failed request already raised out of serve_all; a failed warmup
+    # compile is only recorded, and must not pass for a clean run
+    if service.warmup.failed:
+        for shape, err in sorted(service.warmup.failed.items()):
+            print(f"warmup of padded batch {shape} failed: {err!r}")
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -33,6 +33,10 @@ __all__ = [
 ]
 
 
+#: grid of the second-stage mixture's weighted terms (see second_stage_mix)
+_GRID = float(1 << 20)
+
+
 def _hash_noise(doc_ids: jnp.ndarray, qid: jnp.ndarray, seed: int) -> jnp.ndarray:
     """Deterministic per-(query, doc) pseudo-feature in [0, 1) — stands in
     for the second stage's non-lexical ML features (links, clicks, ...)."""
@@ -57,18 +61,28 @@ def second_stage_mix(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
     its doc shards and still run bit-identical mixing arithmetic on each
     local (Q, width) block.  ``doc_ids`` are the global ids of the block's
     columns (the noise hash keys on them).
+
+    Each weighted term is rounded to a multiple of ``2**-20`` before the
+    terms are added, so the sum (below 2) is exact in float32.  Without
+    that, a compiler that contracts ``w * x + y`` into a fused
+    multiply-add rounds the score differently depending on how the
+    program around it was fused — eager vs jitted, batch vs slot group —
+    and two paths that must agree bit for bit disagree on near-ties.
     """
 
     def norm(x, lo, hi):
         return (x - lo) / jnp.maximum(hi - lo, 1e-9)
 
+    def term(w, x):
+        return jnp.round(w * x * _GRID) / _GRID
+
     (b_lo, b_hi), (l_lo, l_hi), (t_lo, t_hi) = bounds
     prior = 1.0 / jnp.log(2.0 + doc_len.astype(jnp.float32))
     noise = jax.vmap(lambda q: _hash_noise(doc_ids, q, seed))(qids)
-    return (0.45 * norm(acc_bm25, b_lo, b_hi)
-            + 0.25 * norm(acc_lm, l_lo, l_hi)
-            + 0.15 * norm(acc_tfidf, t_lo, t_hi)
-            + 0.05 * prior[None, :] + noise_weight * noise)
+    return (term(0.45, norm(acc_bm25, b_lo, b_hi))
+            + term(0.25, norm(acc_lm, l_lo, l_hi))
+            + term(0.15, norm(acc_tfidf, t_lo, t_hi))
+            + term(0.05, prior[None, :]) + term(noise_weight, noise))
 
 
 def second_stage_scores(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,

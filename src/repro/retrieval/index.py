@@ -22,6 +22,7 @@ merged stream, at the kernel's posting-block granularity).
 from __future__ import annotations
 
 import math
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import jax
@@ -199,12 +200,29 @@ def _segment_quantiles(sorted_vals: np.ndarray, offsets: np.ndarray,
     return np.where(lens > 0, out, 0.0).astype(np.float32)
 
 
+def _sort_by_term(scores: np.ndarray, term_of: np.ndarray):
+    """(scores, terms) sorted by (term, score) ascending.
+
+    One direct sort of packed uint64 keys — term in the high word, the
+    float32 score mapped to an order-preserving uint32 in the low word —
+    instead of an indirect two-key lexsort, which is over ten times
+    slower at a deployment's postings count."""
+    bits = scores.astype(np.float32).view(np.uint32)
+    neg = (bits >> 31).astype(bool)
+    low = np.where(neg, ~bits, bits | np.uint32(0x80000000))
+    key = (term_of.astype(np.uint64) << np.uint64(32)) | low
+    key.sort()
+    low = (key & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    pos = (low >> 31).astype(bool)
+    bits = np.where(pos, low & np.uint32(0x7FFFFFFF), ~low)
+    return bits.view(np.float32), (key >> np.uint64(32)).astype(np.int64)
+
+
 def _term_statistics(scores: np.ndarray, term_of: np.ndarray,
                      vocab: int) -> np.ndarray:
     """9 stats per term for one scorer's posting scores. O(nnz log nnz)."""
-    order = np.lexsort((scores, term_of))
-    s = scores[order].astype(np.float64)
-    t = term_of[order]
+    s, t = _sort_by_term(scores, term_of)
+    s = s.astype(np.float64)
     counts = np.bincount(t, minlength=vocab).astype(np.int64)
     offsets = np.concatenate([[0], np.cumsum(counts)])
     lens = np.maximum(counts, 1)
@@ -233,6 +251,21 @@ def _term_statistics(scores: np.ndarray, term_of: np.ndarray,
     return out
 
 
+def _impact_order(term_of: np.ndarray, impact: np.ndarray,
+                  doc_ids: np.ndarray, levels: int,
+                  vocab: int) -> np.ndarray:
+    """Posting order of the impact-ordered layout: (term, -impact, doc).
+
+    The (term, doc) pairs are unique, so one packed-key argsort gives the
+    three-key lexsort's order whenever the key fits in 64 bits."""
+    if vocab * (levels + 1) < 1 << 31:
+        key = (((term_of.astype(np.uint64) * np.uint64(levels + 1)
+                 + (levels - impact).astype(np.uint64)) << np.uint64(32))
+               | doc_ids.astype(np.uint32).astype(np.uint64))
+        return np.argsort(key)
+    return np.lexsort((doc_ids, -impact.astype(np.int32), term_of))
+
+
 def build_index(corpus: Corpus, impact_bits: int = 8) -> InvertedIndex:
     vocab = corpus.config.vocab
     col = scoring.CollectionStats(
@@ -253,20 +286,22 @@ def build_index(corpus: Corpus, impact_bits: int = 8) -> InvertedIndex:
     s_tfidf = np.asarray(scoring.tfidf(tf, df, dlen, col), dtype=np.float32)
     scores = np.stack([s_bm25, s_lm, s_tfidf], axis=-1)
 
-    # Table 1 statistics, per scorer
-    stats = np.stack(
-        [_term_statistics(scores[:, i], term_of, vocab) for i in range(3)],
-        axis=1,
-    )  # (vocab, 3, 9)
-
     # impact quantization (JASS): global linear quantizer over bm25 scores
     lo, hi = float(s_bm25.min()), float(s_bm25.max())
     levels = (1 << impact_bits) - 1
     impact = np.round((s_bm25 - lo) / max(hi - lo, 1e-9) * levels)
     impact = impact.astype(np.uint8 if impact_bits <= 8 else np.uint16)
 
-    # impact-ordered layout: sort postings by (term, -impact, doc)
-    order = np.lexsort((corpus.doc_ids, -impact.astype(np.int32), term_of))
+    # four independent sorts — the Table 1 statistics of each scorer and
+    # the impact-ordered layout — run side by side (numpy drops the GIL
+    # in them)
+    with ThreadPoolExecutor(4) as pool:
+        per_scorer = [pool.submit(_term_statistics, scores[:, i], term_of,
+                                  vocab) for i in range(3)]
+        order = pool.submit(_impact_order, term_of, impact,
+                            corpus.doc_ids, levels, vocab).result()
+        stats = np.stack([f.result() for f in per_scorer],
+                         axis=1)  # (vocab, 3, 9)
     counts = np.bincount(term_of, minlength=vocab).astype(np.int64)
     offsets = np.concatenate([[0], np.cumsum(counts)])
 

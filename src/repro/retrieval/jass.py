@@ -27,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.topk.ref import top_k_lowest_index
+
 __all__ = ["gather_streams", "saat_scores", "saat_scores_masked",
            "rank_from_scores", "saat_rank"]
 
@@ -58,7 +60,9 @@ def gather_streams(offsets: jnp.ndarray, postings_doc: jnp.ndarray,
     qn, ln = query_terms.shape
     docs = docs.reshape(qn, ln * cap)
     imps = imps.reshape(qn, ln * cap)
-    top_imps, top_idx = jax.lax.top_k(imps, cap)        # impact-descending
+    # impact-descending; equal impacts keep their gathered (term, doc)
+    # order, on every backend
+    top_imps, top_idx = top_k_lowest_index(imps, cap)
     top_docs = jnp.take_along_axis(docs, top_idx, axis=1)
     return top_docs.astype(jnp.int32), top_imps
 
@@ -77,7 +81,7 @@ def saat_scores(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
 
 def saat_scores_masked(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
                        rho_vec: jnp.ndarray, n_docs: int, *,
-                       use_kernel: bool = False, interpret: bool = True,
+                       use_kernel: bool = False, interpret: bool,
                        seg_bounds=None, block_p: int = 512,
                        block_d: int = 2048) -> jnp.ndarray:
     """Accumulate the first ``rho_vec[q]`` postings of each query's stream.
@@ -95,7 +99,8 @@ def saat_scores_masked(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
     from ``index.block_doc_bounds`` at the same ``block_p`` — every
     (posting, doc)-block cell whose id range misses the doc tile), so
     cheap queries actually stop early instead of paying a pre-masked
-    full-stream scan.
+    full-stream scan.  ``interpret`` has no default: the caller states
+    whether that kernel runs compiled or interpreted.
     """
     if use_kernel:
         from repro.kernels.impact_scan import ops as is_ops
@@ -118,14 +123,8 @@ def saat_scores_masked(doc_stream: jnp.ndarray, impact_stream: jnp.ndarray,
 def rank_from_scores(scores: jnp.ndarray, depth: int) -> jnp.ndarray:
     """Top-``depth`` doc ids, ties broken by ascending doc id; zero-score
     docs are excluded (padded with -1)."""
-    n_docs = scores.shape[-1]
-
-    def one(s):
-        order = jnp.lexsort((jnp.arange(n_docs), -s))
-        top = order[:depth]
-        return jnp.where(s[top] > 0, top, -1).astype(jnp.int32)
-
-    return jax.vmap(one)(scores)
+    vals, top = top_k_lowest_index(scores, min(depth, scores.shape[-1]))
+    return jnp.where(vals > 0, top, -1).astype(jnp.int32)
 
 
 def saat_rank(doc_stream, impact_stream, n_docs: int, rho: int,

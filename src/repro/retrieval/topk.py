@@ -16,9 +16,11 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.topk.kernel import KP_MAX
 from repro.retrieval import jass
 
-__all__ = ["candidates_topk", "exhaustive_scores", "select_pool"]
+__all__ = ["candidates_topk", "exhaustive_scores", "pool_route",
+           "select_pool"]
 
 
 def exhaustive_scores(doc_stream, impact_stream, n_docs: int) -> jnp.ndarray:
@@ -35,18 +37,29 @@ def candidates_topk(doc_stream, impact_stream, n_docs: int,
     return jass.rank_from_scores(scores, k)
 
 
-def select_pool(scores: jnp.ndarray, depth: int, *,
-                use_kernel: bool = False,
-                interpret: bool = True) -> jnp.ndarray:
+def pool_route(width: int, *, use_kernel: bool) -> str:
+    """The path that selects a ``width``-wide pool: ``"pallas"`` (the
+    blocked top-k kernel, which holds at most KP_MAX per block) on the
+    kernel path, ``"xla"`` (the jnp selection) otherwise — including
+    kernel-path pools wider than KP_MAX.  The serving engine decides it
+    once per program and reports it (``ServingEngine.topk_routes``)."""
+    return "pallas" if use_kernel and width <= KP_MAX else "xla"
+
+
+def select_pool(scores: jnp.ndarray, depth: int, *, route: str,
+                interpret: bool) -> jnp.ndarray:
     """Top-``depth`` doc ids of dense (Q, N) scores, -1 where the score is
-    not positive — ``jass.rank_from_scores`` semantics, optionally routed
-    through the Pallas blocked top-k kernel (``kernels/topk``) on TPU.
+    not positive — ``jass.rank_from_scores`` semantics, on the ``route``
+    that ``pool_route`` chose: the Pallas blocked top-k kernel
+    (``kernels/topk``) or the lexsort of ``rank_from_scores``.
 
     Both paths break ties toward the lower doc id, so kernel and oracle
     select identical pools.
     """
-    if use_kernel:
+    if route == "pallas":
         from repro.kernels.topk import ops as tk_ops
         vals, idxs = tk_ops.topk_select(scores, depth, interpret=interpret)
         return jnp.where(vals > 0, idxs, -1).astype(jnp.int32)
+    if route != "xla":
+        raise ValueError(f"unknown pool route {route!r}")
     return jass.rank_from_scores(scores, depth)

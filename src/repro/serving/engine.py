@@ -60,6 +60,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import obs as obs_lib
+from repro.kernels.topk.ref import top_k_lowest_index
 from repro.retrieval import gold, jass
 from repro.retrieval import topk as topk_lib
 from repro.retrieval.index import (block_doc_bounds, partition_cap,
@@ -124,19 +125,19 @@ def _stage_gather(offsets, pdoc, pimp, pscore, qt, *, cap: int,
 
 def _stage1_rho(ds, im, seg_lo, seg_hi, rho_vec, *, n_docs: int,
                 depth: int, use_kernel: bool, interpret: bool,
-                block_p: int, block_d: int):
+                block_p: int, block_d: int, route: str):
     acc = jass.saat_scores_masked(ds, im, rho_vec, n_docs,
                                   use_kernel=use_kernel,
                                   interpret=interpret,
                                   seg_bounds=(seg_lo, seg_hi),
                                   block_p=block_p, block_d=block_d)
-    return topk_lib.select_pool(acc, depth, use_kernel=use_kernel,
+    return topk_lib.select_pool(acc, depth, route=route,
                                 interpret=interpret)
 
 
 def _stage1_k(ds, im, seg_lo, seg_hi, k_vec, *, n_docs: int, max_k: int,
               use_kernel: bool, interpret: bool, block_p: int,
-              block_d: int):
+              block_d: int, route: str):
     # exhaustive stage-1 scores (rho = P), one shared max-k selection;
     # the per-query pool width is a traced mask over the shared pool
     full = jnp.full(ds.shape[:1], ds.shape[-1], jnp.int32)
@@ -145,7 +146,7 @@ def _stage1_k(ds, im, seg_lo, seg_hi, k_vec, *, n_docs: int, max_k: int,
                                   interpret=interpret,
                                   seg_bounds=(seg_lo, seg_hi),
                                   block_p=block_p, block_d=block_d)
-    pool = topk_lib.select_pool(acc, max_k, use_kernel=use_kernel,
+    pool = topk_lib.select_pool(acc, max_k, route=route,
                                 interpret=interpret)
     keep = jnp.arange(pool.shape[-1])[None, :] < k_vec[:, None]
     return jnp.where(keep, pool, -1)
@@ -247,7 +248,7 @@ def _sched_chunk(ds_b, im_b, lo_b, hi_b, acc, pos, end, *, chunk_p: int,
 
 
 def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
-                        depth: int, n_docs: int, use_kernel: bool,
+                        depth: int, n_docs: int, route: str,
                         interpret: bool):
     """Stages 1b-3 for a retiring group: pool selection over the finished
     accumulator rows, then stage-2 + rerank exactly as the batch path
@@ -257,7 +258,7 @@ def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
     a depth knob passes the static pool width, making the mask a no-op
     (bit-identical to the depth-free program, same executable count)."""
     rows = acc[slot_idx]
-    pool = topk_lib.select_pool(rows, depth, use_kernel=use_kernel,
+    pool = topk_lib.select_pool(rows, depth, route=route,
                                 interpret=interpret)
     stage2 = _stage2(sd_b[slot_idx], s3_b[slot_idx], doc_len, qids,
                      n_docs=n_docs)
@@ -266,9 +267,9 @@ def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
 
 def _sched_finalize_k(acc, sd_b, s3_b, slot_idx, k_vec, dvec, qids,
                       doc_len, *, depth: int, max_k: int, n_docs: int,
-                      use_kernel: bool, interpret: bool):
+                      route: str, interpret: bool):
     rows = acc[slot_idx]
-    pool = topk_lib.select_pool(rows, max_k, use_kernel=use_kernel,
+    pool = topk_lib.select_pool(rows, max_k, route=route,
                                 interpret=interpret)
     keep = jnp.arange(pool.shape[-1])[None, :] < k_vec[:, None]
     pool = jnp.where(keep, pool, -1)
@@ -319,6 +320,12 @@ class ServingEngine:
         self._kern = dict(use_kernel=self.use_kernel,
                           interpret=self.interpret,
                           block_p=self.block_p, block_d=self.block_d)
+        #: pool-selection route per program ("pallas" | "xla"), decided
+        #: here once from the backend and the pool width (the Pallas
+        #: top-k holds at most KP_MAX per block); the scheduler programs
+        #: add "finalize"
+        self.topk_routes = {
+            "stage1": self.topk_route(cfg.depth_pool_width)}
         self._gather = functools.partial(_stage_gather,
                                          cap=cfg.stream_cap,
                                          block_p=self.block_p,
@@ -329,6 +336,10 @@ class ServingEngine:
                                          depth=cfg.rerank_depth)
         self._rerank_dyn = functools.partial(_stage_rerank_dyn,
                                              depth=cfg.rerank_depth)
+
+    def topk_route(self, width: int) -> str:
+        """Route of a ``width``-wide pool selection on this engine."""
+        return topk_lib.pool_route(width, use_kernel=self.use_kernel)
 
     def bind_obs(self, obs) -> None:
         """Attach an observability handle: per-stage spans in ``serve``
@@ -341,12 +352,13 @@ class ServingEngine:
         """stage1 fn + cache name for a given static pool width (the
         shared executable uses ``max_k``; serve_fixed may request wider)."""
         if self.cfg.knob == "rho":
+            depth = self.cfg.rerank_depth
             return ("stage1", functools.partial(
-                _stage1_rho, n_docs=self.n_docs,
-                depth=self.cfg.rerank_depth, **self._kern))
+                _stage1_rho, n_docs=self.n_docs, depth=depth,
+                route=self.topk_route(depth), **self._kern))
         return (f"stage1:{pool_width}", functools.partial(
             _stage1_k, n_docs=self.n_docs, max_k=pool_width,
-            **self._kern))
+            route=self.topk_route(pool_width), **self._kern))
 
     # ------------------------------------------------------ exec cache --
     def _compiled(self, name: str, fn, args):
@@ -562,7 +574,7 @@ def _sh_gather(offsets, pdoc, pimp, pscore, qt, *, cap: int,
 def _sh_stage1_local(ds_l, im_l, seg_lo, seg_hi, gpos, pvec, *,
                      knob: str, axis: str, width: int, kl: int,
                      use_kernel: bool, interpret: bool, block_p: int,
-                     block_d: int):
+                     block_d: int, route: str):
     """Local stage 1 over the owned partition: rho-masked accumulation +
     this shard's top-``kl`` survivors (values, global doc ids).
 
@@ -584,14 +596,20 @@ def _sh_stage1_local(ds_l, im_l, seg_lo, seg_hi, gpos, pvec, *,
                                   interpret=interpret,
                                   seg_bounds=(seg_lo, seg_hi),
                                   block_p=block_p, block_d=block_d)
-    if use_kernel:
-        from repro.kernels.topk import ops as tk_ops
-        v, i = tk_ops.topk_select(acc, kl, interpret=interpret)
-    else:
-        v, i = jax.lax.top_k(acc, kl)
+    v, i = _local_topk(acc, kl, route=route, interpret=interpret)
     lo = jax.lax.axis_index(axis) * width
     gi = (i + lo).astype(jnp.int32)
     return v, gi
+
+
+def _local_topk(acc, kl: int, *, route: str, interpret: bool):
+    """A shard's top-``kl`` (values, local ids) on the route the engine
+    chose (``retrieval.topk.pool_route``): the Pallas blocked top-k or
+    ``top_k_lowest_index`` — identical values and lowest-index ties."""
+    if route == "pallas":
+        from repro.kernels.topk import ops as tk_ops
+        return tk_ops.topk_select(acc, kl, interpret=interpret)
+    return top_k_lowest_index(acc, kl)
 
 
 def _sh_allgather(v, gi, *, axis: str):
@@ -621,7 +639,7 @@ def _sh_merge_k(vflat, gflat, k_vec, *, max_k: int):
 
 
 def _pool_from_local(acc, depth: int, *, axis: str, width: int,
-                     use_kernel: bool = False, interpret: bool = True):
+                     route: str, interpret: bool):
     """select_pool over doc-sharded accumulators: local top-k clamped to
     the shard width, global ids from the true shard offset, merged with
     lowest-doc-id tie-breaking (bit-identical to rank_from_scores'
@@ -629,17 +647,13 @@ def _pool_from_local(acc, depth: int, *, axis: str, width: int,
     and are masked to -1 by the same >0 rule as real zero-score docs).
 
     The per-shard local scores are exactly the blocked-top-k stage-1
-    shape ``kernels/topk`` was designed for, so the kernel path runs
+    shape ``kernels/topk`` was designed for, so the "pallas" route runs
     ``topk_select`` (Pallas block extraction + merge; identical values
-    and lowest-index ties, falling back to the oracle beyond KP_MAX)
-    where the oracle path runs ``lax.top_k``."""
+    and lowest-index ties) where the "xla" route runs
+    ``top_k_lowest_index``."""
     from repro.distrib import collectives
     kl = min(depth, width)
-    if use_kernel:
-        from repro.kernels.topk import ops as tk_ops
-        v, i = tk_ops.topk_select(acc, kl, interpret=interpret)
-    else:
-        v, i = jax.lax.top_k(acc, kl)
+    v, i = _local_topk(acc, kl, route=route, interpret=interpret)
     lo = jax.lax.axis_index(axis) * width
     gi = (i + lo).astype(jnp.int32)
     mv, mg = collectives.merge_local_topk(v, gi, depth, axis)
@@ -815,6 +829,9 @@ class ShardedServingEngine(ServingEngine):
                                     out_specs=out_specs)
 
         self._smap = smap
+        # a shard selects its local survivors: at most shard_width wide
+        self.topk_routes = {"stage1": self.topk_route(
+            min(cfg.depth_pool_width, self.shard_width))}
         self._stat = dict(axis=axis, width=self.shard_width)
         self._s1_stat = dict(**self._stat, **self._kern)
         self._gather = smap(
@@ -866,14 +883,16 @@ class ShardedServingEngine(ServingEngine):
         if self.cfg.knob == "rho":
             kl = min(self.cfg.rerank_depth, self.shard_width)
             return ("stage1", self._smap(functools.partial(
-                _sh_stage1_local, knob="rho", kl=kl, **self._s1_stat),
+                _sh_stage1_local, knob="rho", kl=kl,
+                route=self.topk_route(kl), **self._s1_stat),
                 self._specs["stage1"],
                 (P(self._specs["stage1"][0][0], self.axis),) * 2))
         kl = min(pool_width, self.shard_width)
         name = ("stage1" if pool_width == self.max_k
                 else f"stage1:{pool_width}")
         return (name, self._smap(functools.partial(
-            _sh_stage1_local, knob="k", kl=kl, **self._s1_stat),
+            _sh_stage1_local, knob="k", kl=kl,
+            route=self.topk_route(kl), **self._s1_stat),
             self._specs["stage1"],
             (P(self._specs["stage1"][0][0], self.axis),) * 2))
 
@@ -1098,9 +1117,10 @@ class SchedPrograms:
             _sched_chunk, chunk_p=self.chunk_p, bounds_p=self.bounds_p,
             n_docs=engine.n_docs, use_kernel=engine.use_kernel,
             interpret=engine.interpret, block_d=engine.block_d)
+        route = engine.topk_route(cfg.depth_pool_width)
+        engine.topk_routes["finalize"] = route
         common = dict(depth=cfg.rerank_depth, n_docs=engine.n_docs,
-                      use_kernel=engine.use_kernel,
-                      interpret=engine.interpret)
+                      route=route, interpret=engine.interpret)
         if cfg.knob == "rho":
             self._final_fn = functools.partial(_sched_finalize_rho,
                                                **common)
@@ -1305,7 +1325,7 @@ def _ssched_chunk(ds_b, im_b, lo_b, hi_b, gp_b, acc, pos, end, *,
 
 def _ssched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len,
                          *, depth: int, axis: str, width: int,
-                         n_docs: int, use_kernel: bool, interpret: bool):
+                         n_docs: int, route: str, interpret: bool):
     """Sharded stages 1b-3 for a retiring group: cross-shard pool merge
     over the finished local accumulator rows, partitioned stage 2,
     pmax-assembled rerank — the batch-once sharded tail on slot rows.
@@ -1313,7 +1333,7 @@ def _ssched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len,
     when no depth knob is live — a no-op mask)."""
     rows = acc[slot_idx]
     pool = _pool_from_local(rows, depth, axis=axis, width=width,
-                            use_kernel=use_kernel, interpret=interpret)
+                            route=route, interpret=interpret)
     stage2 = _sh_stage2(sd_b[slot_idx], s3_b[slot_idx], doc_len, qids,
                         axis=axis, width=width, n_docs=n_docs)
     return _sh_rerank(stage2, _depth_mask(pool, dvec), axis=axis,
@@ -1322,11 +1342,11 @@ def _ssched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len,
 
 def _ssched_finalize_k(acc, sd_b, s3_b, slot_idx, k_vec, dvec, qids,
                        doc_len, *, depth: int, max_k: int, axis: str,
-                       width: int, n_docs: int, use_kernel: bool,
+                       width: int, n_docs: int, route: str,
                        interpret: bool):
     rows = acc[slot_idx]
     pool = _pool_from_local(rows, max_k, axis=axis, width=width,
-                            use_kernel=use_kernel, interpret=interpret)
+                            route=route, interpret=interpret)
     keep = jnp.arange(pool.shape[-1])[None, :] < k_vec[:, None]
     pool = jnp.where(keep, pool, -1)
     stage2 = _sh_stage2(sd_b[slot_idx], s3_b[slot_idx], doc_len, qids,
@@ -1424,9 +1444,10 @@ class ShardedSchedPrograms(SchedPrograms):
                               use_kernel=e.use_kernel,
                               interpret=e.interpret, block_d=e.block_d),
             self._arg_specs["chunk"], sacc)
+        route = e.topk_route(min(cfg.depth_pool_width, width))
+        e.topk_routes["finalize"] = route
         common = dict(depth=cfg.rerank_depth, axis=axis, width=width,
-                      n_docs=e.n_docs, use_kernel=e.use_kernel,
-                      interpret=e.interpret)
+                      n_docs=e.n_docs, route=route, interpret=e.interpret)
         if cfg.knob == "rho":
             self._final_fn = smap(
                 functools.partial(_ssched_finalize_rho, **common),

@@ -191,16 +191,18 @@ class RetrievalServer:
         # that jax.transfer_guard("disallow") rightly rejects
         node_params = jax.device_put(node_params)
         kind, depth = casc.kind, casc.max_depth
-        stats_, ctf_, df_ = self.stats, self.ctf, self.df
 
-        def _predict(node_params, thresholds, q):
-            x = feat_lib.query_features(q, stats_, ctf_, df_)
+        # the per-term statistics tables are operands too: closed over,
+        # they would be baked into every predict executable as constants
+        # (hundreds of MB at a deployment's vocabulary)
+        def _predict(node_params, thresholds, q, tables):
+            x = feat_lib.query_features(q, *tables)
             p0 = cascade_lib.proba0_from_params(kind, node_params, x,
                                                 depth)
             return cascade_lib.classes_from_proba(p0, thresholds)
 
-        def _margin(node_params, thresholds, q):
-            x = feat_lib.query_features(q, stats_, ctf_, df_)
+        def _margin(node_params, thresholds, q, tables):
+            x = feat_lib.query_features(q, *tables)
             p0 = cascade_lib.proba0_from_params(kind, node_params, x,
                                                 depth)
             return jnp.min(jnp.abs(p0 - thresholds[None, :]), axis=1)
@@ -247,7 +249,8 @@ class RetrievalServer:
                                 self.engine.batch_multiple, fill=-1)
         node_params, thresholds = live[knob]
         return np.asarray(self._predict_fns[knob](
-            node_params, thresholds, jnp.asarray(qt)))[:n]
+            node_params, thresholds, jnp.asarray(qt),
+            (self.stats, self.ctf, self.df)))[:n]
 
     def predict_margin(self, query_terms: np.ndarray,
                        knob: str | None = None) -> np.ndarray:
@@ -270,7 +273,8 @@ class RetrievalServer:
                                 self.engine.batch_multiple, fill=-1)
         node_params, thresholds = live
         return np.asarray(self._margin_fns[knob](
-            node_params, thresholds, jnp.asarray(qt)))[:n]
+            node_params, thresholds, jnp.asarray(qt),
+            (self.stats, self.ctf, self.df)))[:n]
 
     def swap_predictor(self, node_params, thresholds=None, *,
                        version: int | None = None,

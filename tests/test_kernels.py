@@ -29,9 +29,9 @@ def test_flash_attention_sweep(b, s, hq, hkv, hd, causal, window):
     k = jnp.asarray(R.normal(size=(b, s, hkv, hd)).astype(np.float32))
     v = jnp.asarray(R.normal(size=(b, s, hkv, hd)).astype(np.float32))
     out = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                 block_q=32, block_kv=32)
+                                 block_q=32, block_kv=32, interpret=True)
     ref = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                 use_kernel=False)
+                                 use_kernel=False, interpret=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -42,8 +42,9 @@ def test_flash_attention_dtypes(dtype, tol):
     q = jnp.asarray(R.normal(size=(1, 64, 4, 32))).astype(dt)
     k = jnp.asarray(R.normal(size=(1, 64, 2, 32))).astype(dt)
     v = jnp.asarray(R.normal(size=(1, 64, 2, 32))).astype(dt)
-    out = fa_ops.flash_attention(q, k, v, block_q=32, block_kv=32)
-    ref = fa_ops.flash_attention(q, k, v, use_kernel=False)
+    out = fa_ops.flash_attention(q, k, v, block_q=32, block_kv=32,
+                                 interpret=True)
+    ref = fa_ops.flash_attention(q, k, v, use_kernel=False, interpret=False)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
@@ -62,9 +63,9 @@ def test_impact_scan_sweep(q, p, nd, rho, bp, bd):
     docs = jnp.asarray(R.integers(-1, nd, (q, p)).astype(np.int32))
     imps = jnp.asarray((R.random((q, p)) * 255).astype(np.float32))
     a = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
-                               block_p=bp, block_d=bd)
+                               block_p=bp, block_d=bd, interpret=True)
     b = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
-                               use_kernel=False)
+                               use_kernel=False, interpret=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
 
 
@@ -73,7 +74,8 @@ def test_impact_scan_rho_semantics():
     docs = jnp.asarray(np.array([[0, 1, 2, 3]], np.int32))
     imps = jnp.asarray(np.array([[10., 20., 30., 40.]], np.float32))
     a = np.asarray(is_ops.saat_accumulate(docs, imps, n_docs=4, rho=2,
-                                          block_p=2, block_d=2))
+                                          block_p=2, block_d=2,
+                                          interpret=True))
     assert list(a[0]) == [10.0, 20.0, 0.0, 0.0]
 
 
@@ -99,9 +101,9 @@ def test_impact_scan_traced_rho_mixed(q, p, nd, bp, bd):
     rho = jnp.asarray(
         np.array([0, 1, p // 2, p + 50][:q], np.int32))
     a = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
-                               block_p=bp, block_d=bd)
+                               block_p=bp, block_d=bd, interpret=True)
     b = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
-                               use_kernel=False)
+                               use_kernel=False, interpret=False)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -114,7 +116,7 @@ def test_impact_scan_constant_rho_bit_identical_to_ref(rho):
     docs, imps = _int_streams(3, 100, 200)
     rho_vec = jnp.full((3,), rho, jnp.int32)
     a = is_ops.saat_accumulate(docs, imps, n_docs=200, rho=rho_vec,
-                               block_p=32, block_d=64)
+                               block_p=32, block_d=64, interpret=True)
     ref = impact_scan_ref(docs, imps, n_docs=200, rho=rho)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(ref))
 
@@ -140,10 +142,10 @@ def test_impact_scan_segment_skips_fewer_cells():
 
     dense, cnt_dense = is_ops.saat_accumulate(
         docs, imps, n_docs=nd, rho=rho, block_p=bp, block_d=bd,
-        with_stats=True)
+        with_stats=True, interpret=True)
     skip, cnt_skip = is_ops.saat_accumulate(
         docs, imps, n_docs=nd, rho=rho, block_p=bp, block_d=bd,
-        seg_bounds=seg, with_stats=True)
+        seg_bounds=seg, with_stats=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(dense), np.asarray(skip))
     analytic = int(live_cell_count(rho, *seg, p=p, n_docs=nd,
                                    block_p=bp, block_d=bd))
@@ -166,7 +168,8 @@ def test_impact_scan_exhausted_stream_blocks_skipped():
     rho = jnp.asarray([16], jnp.int32)
     acc, cnt = is_ops.saat_accumulate(docs, imps, n_docs=8, rho=rho,
                                       block_p=4, block_d=8,
-                                      seg_bounds=seg, with_stats=True)
+                                      seg_bounds=seg, with_stats=True,
+                                      interpret=True)
     assert int(np.asarray(cnt).sum()) == 1      # only the live block ran
     assert list(np.asarray(acc)[0, :4]) == [5.0, 5.0, 5.0, 5.0]
 
@@ -178,28 +181,30 @@ def test_impact_scan_rho_zero_skips_kernel_launch(monkeypatch):
 
     monkeypatch.setattr("repro.kernels.impact_scan.ops._kernel", boom)
     docs, imps = _int_streams(2, 32, 40)
-    out = is_ops.saat_accumulate(docs, imps, n_docs=40, rho=0)
+    out = is_ops.saat_accumulate(docs, imps, n_docs=40, rho=0, interpret=True)
     assert np.asarray(out).shape == (2, 40) and not np.asarray(out).any()
     out, cnt = is_ops.saat_accumulate(docs, imps, n_docs=40, rho=0,
-                                      with_stats=True)
+                                      with_stats=True, interpret=True)
     assert not np.asarray(out).any() and not np.asarray(cnt).any()
 
 
 def test_impact_scan_validation_errors():
     docs, imps = _int_streams(2, 32, 40)
     with pytest.raises(ValueError, match="rho must be >= 0"):
-        is_ops.saat_accumulate(docs, imps, n_docs=40, rho=-1)
+        is_ops.saat_accumulate(docs, imps, n_docs=40, rho=-1, interpret=True)
     with pytest.raises(ValueError, match="integer dtype"):
         is_ops.saat_accumulate(docs, imps, n_docs=40,
-                               rho=jnp.asarray([1.0, 2.0]))
+                               rho=jnp.asarray([1.0, 2.0]), interpret=True)
     with pytest.raises(ValueError, match="shaped"):
         is_ops.saat_accumulate(docs, imps, n_docs=40,
-                               rho=jnp.asarray([1, 2, 3], jnp.int32))
+                               rho=jnp.asarray([1, 2, 3], jnp.int32),
+                               interpret=True)
     with pytest.raises(ValueError, match="segment bounds"):
         bad = jnp.zeros((2, 7), jnp.int32)
         is_ops.saat_accumulate(docs, imps, n_docs=40,
                                rho=jnp.asarray([1, 2], jnp.int32),
-                               block_p=8, seg_bounds=(bad, bad))
+                               block_p=8, seg_bounds=(bad, bad),
+                               interpret=True)
 
 
 def test_oracle_with_stats_matches_kernel_counts():
@@ -213,19 +218,20 @@ def test_oracle_with_stats_matches_kernel_counts():
     seg = block_doc_bounds(docs, block_p=bp, n_docs=nd)
     acc_k, cnt_k = is_ops.saat_accumulate(
         docs, imps, n_docs=nd, rho=rho, block_p=bp, block_d=bd,
-        seg_bounds=seg, with_stats=True)
+        seg_bounds=seg, with_stats=True, interpret=True)
     acc_o, cnt_o = is_ops.saat_accumulate(
         docs, imps, n_docs=nd, rho=rho, block_p=bp, block_d=bd,
-        seg_bounds=seg, with_stats=True, use_kernel=False)
+        seg_bounds=seg, with_stats=True, use_kernel=False, interpret=False)
     np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_o))
     np.testing.assert_array_equal(np.asarray(cnt_k), np.asarray(cnt_o))
     # and without seg bounds both synthesize the same full-range bounds
     _, cd_k = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
                                      block_p=bp, block_d=bd,
-                                     with_stats=True)
+                                     with_stats=True, interpret=True)
     _, cd_o = is_ops.saat_accumulate(docs, imps, n_docs=nd, rho=rho,
                                      block_p=bp, block_d=bd,
-                                     with_stats=True, use_kernel=False)
+                                     with_stats=True, use_kernel=False,
+                                     interpret=False)
     np.testing.assert_array_equal(np.asarray(cd_k), np.asarray(cd_o))
 
 
@@ -237,8 +243,8 @@ def test_oracle_with_stats_matches_kernel_counts():
 ])
 def test_topk_sweep(q, n, k, bn):
     s = jnp.asarray(R.normal(size=(q, n)).astype(np.float32))
-    v1, i1 = tk_ops.topk_select(s, k, block_n=bn)
-    v2, i2 = tk_ops.topk_select(s, k, use_kernel=False)
+    v1, i1 = tk_ops.topk_select(s, k, block_n=bn, interpret=True)
+    v2, i2 = tk_ops.topk_select(s, k, use_kernel=False, interpret=False)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2))
 
@@ -251,10 +257,20 @@ def test_block_topk_rejects_invalid_kp():
     s = jnp.asarray(R.normal(size=(2, 512)).astype(np.float32))
     for kp in (0, -3, KP_MAX + 1, 500):
         with pytest.raises(ValueError, match=r"kp must be in \[1, 128\]"):
-            block_topk(s, kp=kp, block_n=256)
-    # the oracle fallback in topk_select still serves k > KP_MAX exactly
-    # (checked against lax.top_k, not against its own code path)
-    v1, i1 = tk_ops.topk_select(s, KP_MAX + 50)
+            block_topk(s, kp=kp, block_n=256, interpret=True)
+    # topk_select no longer falls back silently: the kernel path raises
+    # past KP_MAX, and pool_route sends such widths to the sort path,
+    # which serves them exactly (checked against lax.top_k, not against
+    # its own code path)
+    from repro.retrieval import topk as topk_lib
+
+    with pytest.raises(ValueError, match=r"kp must be in \[1, 128\]"):
+        tk_ops.topk_select(s, KP_MAX + 50, interpret=True)
+    assert topk_lib.pool_route(KP_MAX, use_kernel=True) == "pallas"
+    assert topk_lib.pool_route(KP_MAX + 50, use_kernel=True) == "xla"
+    assert topk_lib.pool_route(10, use_kernel=False) == "xla"
+    v1, i1 = tk_ops.topk_select(s, KP_MAX + 50, use_kernel=False,
+                                interpret=False)
     vr, ir = jax.lax.top_k(s, KP_MAX + 50)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(ir))
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(vr))
@@ -262,8 +278,45 @@ def test_block_topk_rejects_invalid_kp():
 
 def test_topk_ties_prefer_low_index():
     s = jnp.asarray(np.array([[1.0, 5.0, 5.0, 0.0, 5.0]], np.float32))
-    _, idx = tk_ops.topk_select(s, 3, block_n=2)
+    _, idx = tk_ops.topk_select(s, 3, block_n=2, interpret=True)
     assert list(np.asarray(idx)[0]) == [1, 2, 4]
+
+
+_LAX_TOP_K = jax.lax.top_k
+
+
+def _top_k_highest_index_ties(x, k):
+    """``lax.top_k`` with equal values highest index first — a backend
+    whose tie order is not XLA:CPU's."""
+    n = x.shape[-1]
+    v, i = _LAX_TOP_K(x[..., ::-1], k)
+    return v, n - 1 - i
+
+
+@pytest.mark.parametrize("backend_ties", ["lowest", "highest"])
+@pytest.mark.parametrize("q,n,k,levels", [
+    (3, 500, 40, 4), (2, 1000, 200, 16), (1, 64, 64, 2), (4, 300, 1, 3),
+])
+def test_top_k_lowest_index_any_backend(monkeypatch, backend_ties, q, n, k,
+                                        levels):
+    """Integer-valued scores (many ties, as JASS accumulators have) select
+    and order exactly as the lexsort oracle, whatever tie order the
+    backend's ``lax.top_k`` has; a smaller k is a prefix of a larger."""
+    from repro.kernels.topk.ref import top_k_lowest_index, topk_ref
+
+    x = jnp.asarray(R.integers(0, levels, (q, n)).astype(np.float32))
+    vr, ir = topk_ref(x, k)
+    if backend_ties == "highest":
+        monkeypatch.setattr(jax.lax, "top_k", _top_k_highest_index_ties)
+        # the stand-in really reorders ties, or the case checks nothing
+        assert not np.array_equal(np.asarray(jax.lax.top_k(x, k)[1]),
+                                  np.asarray(ir))
+    v, i = top_k_lowest_index(x, k)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
+    _, i_half = top_k_lowest_index(x, max(1, k // 2))
+    np.testing.assert_array_equal(np.asarray(i_half),
+                                  np.asarray(ir)[:, :max(1, k // 2)])
 
 
 @settings(max_examples=20, deadline=None)
@@ -272,8 +325,8 @@ def test_topk_property(q, n, k):
     k = min(k, n)
     s = jnp.asarray(np.random.default_rng(q * n + k)
                     .normal(size=(q, n)).astype(np.float32))
-    v1, i1 = tk_ops.topk_select(s, k, block_n=32)
-    v2, i2 = tk_ops.topk_select(s, k, use_kernel=False)
+    v1, i1 = tk_ops.topk_select(s, k, block_n=32, interpret=True)
+    v2, i2 = tk_ops.topk_select(s, k, use_kernel=False, interpret=False)
     assert np.array_equal(np.asarray(i1), np.asarray(i2))
 
 
@@ -286,8 +339,9 @@ def test_topk_property(q, n, k):
 def test_embedding_bag_sweep(v, d, b, l, comb):
     t = jnp.asarray(R.normal(size=(v, d)).astype(np.float32))
     ids = jnp.asarray(R.integers(-1, v, (b, l)).astype(np.int32))
-    o1 = eb_ops.embedding_bag(t, ids, combiner=comb)
-    o2 = eb_ops.embedding_bag(t, ids, combiner=comb, use_kernel=False)
+    o1 = eb_ops.embedding_bag(t, ids, combiner=comb, interpret=True)
+    o2 = eb_ops.embedding_bag(t, ids, combiner=comb, use_kernel=False,
+                              interpret=False)
     # kernel accumulates slots strictly left-to-right; the jnp oracle's
     # sum may reduce in a different order -> allow one-ULP slack
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-5,
@@ -297,7 +351,7 @@ def test_embedding_bag_sweep(v, d, b, l, comb):
 def test_embedding_bag_all_padding():
     t = jnp.asarray(R.normal(size=(10, 4)).astype(np.float32))
     ids = jnp.full((2, 3), -1, jnp.int32)
-    o = eb_ops.embedding_bag(t, ids, combiner="mean")
+    o = eb_ops.embedding_bag(t, ids, combiner="mean", interpret=True)
     assert np.allclose(np.asarray(o), 0.0)
 
 
@@ -307,5 +361,5 @@ def test_embedding_bag_matches_model_layer():
     t = jnp.asarray(R.normal(size=(40, 8)).astype(np.float32))
     ids = jnp.asarray(R.integers(-1, 40, (6, 4)).astype(np.int32))
     np.testing.assert_allclose(
-        np.asarray(eb_ops.embedding_bag(t, ids)),
+        np.asarray(eb_ops.embedding_bag(t, ids, interpret=True)),
         np.asarray(E.bag_fixed(t, ids)), rtol=1e-5, atol=1e-6)

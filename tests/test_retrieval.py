@@ -1,5 +1,6 @@
 """Retrieval substrate: index vs brute force, JASS semantics, gold runs."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -157,3 +158,51 @@ def test_features_shape_and_padding(small):
         [q.terms, np.full((q.n_queries, 3), -1, np.int32)], axis=1)
     f2 = feat_lib.query_features(jnp.asarray(wider), stats, ctf, df)
     assert np.allclose(np.asarray(f), np.asarray(f2), atol=1e-5)
+
+
+def test_packed_key_sorts_match_lexsort():
+    """The index build's packed-key sorts give the lexsorts' results:
+    (term, score) order over negative, zero and positive float32 scores,
+    and the (term, -impact, doc) posting order."""
+    r = np.random.default_rng(5)
+    n, vocab = 5000, 300
+    term_of = r.integers(0, vocab, n).astype(np.int64)
+    scores = np.concatenate([r.normal(size=n - 4) * 7,
+                             [0.0, -0.5, 3e-39, -3e-39]]).astype(np.float32)
+    s, t = index_lib._sort_by_term(scores, term_of)
+    order = np.lexsort((scores, term_of))
+    np.testing.assert_array_equal(t, term_of[order])
+    np.testing.assert_array_equal(s, scores[order])
+
+    pairs = np.unique(r.integers(0, vocab * 900, n))    # unique (term, doc)
+    term_of, doc_ids = (pairs // 900).astype(np.int64), (pairs % 900)
+    impact = r.integers(0, 256, len(pairs)).astype(np.uint8)
+    np.testing.assert_array_equal(
+        index_lib._impact_order(term_of, impact, doc_ids.astype(np.int32),
+                                255, vocab),
+        np.lexsort((doc_ids, -impact.astype(np.int32), term_of)))
+
+
+def test_rank_from_scores_ties_prefer_low_doc_id():
+    """top_k-based ranking keeps the (score desc, doc id asc) order of a
+    lexsort, drops zero scores, and clamps depth to the doc count."""
+    s = jnp.asarray(np.array([[0., 2., 5., 2., 5., 0., 1.],
+                              [0., 0., 0., 0., 0., 0., 0.]], np.float32))
+    got = np.asarray(jass.rank_from_scores(s, 5))
+    np.testing.assert_array_equal(got, [[2, 4, 1, 3, 6], [-1] * 5])
+    assert jass.rank_from_scores(s, 50).shape == (2, 7)
+
+
+def test_second_stage_scores_identical_eager_and_jitted():
+    """Stage-2 scores do not depend on how the program around them was
+    fused: the per-bucket reference evaluates them op by op, the engine
+    inside one jitted stage, and the rankings must agree bit for bit."""
+    r = np.random.default_rng(9)
+    q, n = 8, 20_000
+    accs = [jnp.asarray((r.random((q, n)) * 30).astype(np.float32))
+            for _ in range(3)]
+    doc_len = jnp.asarray(r.integers(5, 300, n).astype(np.int32))
+    qids = jnp.arange(q, dtype=jnp.int32)
+    eager = gold.second_stage_scores(*accs, doc_len, qids)
+    jitted = jax.jit(gold.second_stage_scores)(*accs, doc_len, qids)
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))

@@ -1,0 +1,109 @@
+"""Compile-only checks for a described TPU v5e: the served path's Pallas
+kernels at one chip's deployment widths (Q=64 queries, P=4096 postings,
+2,210,456 docs, blocks 512/2048).
+
+Nothing runs: each test lowers and compiles for a chip that is described,
+not attached, which raises what the chip's compiler would refuse (block
+shapes off the (8, 128) tiling, scoped-VMEM overruns).  The topology is
+described inside a module fixture — never at import — so every xdist
+worker collects the same tests and only the one that runs them loads the
+TPU compiler."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.impact_scan.kernel import impact_scan, posting_blocks
+from repro.kernels.topk import ops as tk_ops
+from repro.serving import engine as engine_lib
+
+Q, P, N_DOCS = 64, 4096, 2_210_456
+BLOCK_P, BLOCK_D = 512, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of any cache a caller configured
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _stream_args(spec):
+    _, n_p = posting_blocks(P, BLOCK_P)
+    return (spec((Q, P), jnp.int32), spec((Q, P), jnp.float32),
+            spec((Q,), jnp.int32), spec((Q, n_p), jnp.int32),
+            spec((Q, n_p), jnp.int32))
+
+
+@pytest.mark.parametrize("with_stats", [False, True])
+def test_impact_scan_compiles_for_v5e(spec, no_cache, with_stats):
+    fn = jax.jit(lambda *a: impact_scan(
+        *a, n_docs=N_DOCS, block_p=BLOCK_P, block_d=BLOCK_D,
+        with_stats=with_stats, interpret=False))
+    compiled = fn.lower(*_stream_args(spec)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # the (Q, n_docs) f32 accumulator is the output, not a blow-up of it
+    assert mem.output_size_in_bytes < 2 * Q * N_DOCS * 4
+
+
+def test_topk_select_compiles_for_v5e(spec, no_cache):
+    fn = jax.jit(lambda s: tk_ops.topk_select(s, 100, interpret=False))
+    compiled = fn.lower(spec((Q, N_DOCS), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stage1_rho_compiles_for_v5e(spec, no_cache):
+    """The engine's jitted stage-1 body under the rho knob: traced-rho
+    impact_scan, then pool selection on the "pallas" route."""
+    import functools
+    body = functools.partial(
+        engine_lib._stage1_rho, n_docs=N_DOCS, depth=100, use_kernel=True,
+        interpret=False, block_p=BLOCK_P, block_d=BLOCK_D, route="pallas")
+    ds, im, rho, seg_lo, seg_hi = _stream_args(spec)
+    compiled = jax.jit(body).lower(ds, im, seg_lo, seg_hi, rho).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_stage1_k_compiles_for_v5e(spec, no_cache):
+    """The k knob's stage 1: impact_scan, then the 2,000-wide shared pool
+    on the "xla" route (``top_k_lowest_index``), within one chip."""
+    import functools
+    body = functools.partial(
+        engine_lib._stage1_k, n_docs=N_DOCS, max_k=2000, use_kernel=True,
+        interpret=False, block_p=BLOCK_P, block_d=BLOCK_D, route="xla")
+    ds, im, k_vec, seg_lo, seg_hi = _stream_args(spec)
+    compiled = jax.jit(body).lower(ds, im, seg_lo, seg_hi, k_vec).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # a 16 GiB chip also holds the ~2.2 GiB index and stage 2's ~5.3 GiB
+    # of temporaries: stage 1's own must stay a few accumulators
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
