@@ -2,8 +2,7 @@
 
 One `Observability` handle bundles the two recording surfaces —
 a `TraceRecorder` (bounded per-query/per-stage spans) and a
-`MetricsRegistry` (deterministic counters + machine-dependent
-gauges/histograms).  Serving classes accept the handle through
+`MetricsRegistry` (deterministic counters).  Serving classes accept the handle through
 ``bind_obs``/constructor args and default to `NULL_OBS`, whose
 recorders are disabled: handles still carry timestamps (so derived
 timings keep working) but nothing is stored and no lock is touched.
@@ -17,14 +16,14 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from repro.obs.metrics import (NULL_METRIC, NULL_REGISTRY, Counter, Gauge,
-                               Histogram, MetricsRegistry)
+from repro.obs.metrics import (NULL_METRIC, NULL_REGISTRY, Counter,
+                               MetricsRegistry)
 from repro.obs.trace import NULL_TRACE, SpanHandle, TraceRecorder
 
 __all__ = [
     "Observability", "NULL_OBS", "TraceRecorder", "SpanHandle",
-    "NULL_TRACE", "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "NULL_REGISTRY", "NULL_METRIC",
+    "NULL_TRACE", "MetricsRegistry", "Counter", "NULL_REGISTRY",
+    "NULL_METRIC",
 ]
 
 

@@ -120,25 +120,12 @@ def _prom_name(name: str) -> str:
 
 
 def prometheus_text(metrics) -> str:
-    """Prometheus text exposition format, one block per metric."""
+    """Prometheus text exposition format, one block per counter."""
     snap = metrics.snapshot()
     lines = []
     for name, v in snap["counters"].items():
         p = _prom_name(name)
         lines += [f"# TYPE {p} counter", f"{p} {v}"]
-    for name, v in snap["gauges"].items():
-        p = _prom_name(name)
-        lines += [f"# TYPE {p} gauge", f"{p} {v}"]
-    for name, v in snap["histograms"].items():
-        p = _prom_name(name)
-        lines.append(f"# TYPE {p} histogram")
-        h = metrics.histogram(name)
-        acc = 0
-        for le, c in zip(h.upper_bounds(), v["counts"]):
-            acc += c
-            tag = "+Inf" if le == float("inf") else f"{le:g}"
-            lines.append(f'{p}_bucket{{le="{tag}"}} {acc}')
-        lines += [f"{p}_sum {v['sum']}", f"{p}_count {v['n']}"]
     return "\n".join(lines) + "\n"
 
 

@@ -10,15 +10,17 @@ never device values).  Three recording styles cover every call site:
 - ``with trace.span("engine.stage1") as sp: ...`` — context manager,
   balanced even on exceptions; ``sp.dur_ms`` is readable after exit, so
   the engine's per-stage timings dict is *derived from* the span rather
-  than timed twice.
+  than timed twice.  While the recorder is enabled the span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profiler trace
+  holds it in its host plane on the device's clock.
 - ``h = trace.begin(...)`` / ``trace.end(h)`` — explicit, for spans
   whose begin and end live on different threads (a request's lifetime
   from admission to resolve).  ``end`` is idempotent so the resolve
   path and the cancellation path may both close the same span.
 - ``trace.record(name, t0, t1, ...)`` — retrospective, for windows the
-  caller already timed with its own clock (the scheduler's tick steps,
-  per-slot occupancy from ``t_admit``/``t_retire``).  Balanced by
-  construction.
+  caller already timed with its own clock (the scheduler's working
+  ``tick``, per-slot occupancy from ``t_admit``/``t_retire``, a stall).
+  Balanced by construction.
 
 The recorder is a bounded ring: once ``capacity`` completed spans are
 held, the oldest is overwritten and ``n_dropped`` accounts for it —
@@ -31,9 +33,8 @@ legal and calling out while holding an obs lock is not done anywhere.
 A disabled recorder (``NULL_TRACE``) still stamps ``t0``/``t1`` on the
 handles it returns — so code that derives timings from ``sp.dur_ms``
 works identically with observability off — but never touches the lock,
-the ring, or the counters.  ``enabled`` is fixed at construction; the
-obs-off cost is one clock read per boundary, gated by the committed
-``obs_overhead_bounded`` ratio in ``artifacts/BENCH_serving.json``.
+the ring, the counters or the profiler.  ``enabled`` is fixed at
+construction; the obs-off cost is one clock read per boundary.
 
 ``ctx(batch=..., tick=...)`` pushes thread-local join keys merged into
 the attrs of every span *begun* on that thread, which is how
@@ -51,6 +52,8 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 
 class SpanHandle:
@@ -173,7 +176,11 @@ class TraceRecorder:
              tick: int = -1, **attrs):
         h = self.begin(name, qid=qid, slot=slot, tick=tick, **attrs)
         try:
-            yield h
+            if self.enabled:
+                with TraceAnnotation(name):
+                    yield h
+            else:
+                yield h
         finally:
             self.end(h)
 

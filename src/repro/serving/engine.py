@@ -72,6 +72,15 @@ __all__ = ["SchedPrograms", "SchedState", "ServingEngine",
            "ShardedSchedPrograms", "ShardedServingEngine"]
 
 
+def named(fn, name: str):
+    """``fn`` under ``name``: ``jax.jit`` names the module it lowers
+    after the function (a bare ``functools.partial`` lowers as
+    ``jit__unknown``)."""
+    f = functools.partial(fn)
+    f.__name__ = f.__qualname__ = name
+    return f
+
+
 class _PendingCompile:
     """In-flight marker in the executable cache (see ``_compiled``)."""
 
@@ -361,8 +370,11 @@ class ServingEngine:
             route=self.topk_route(pool_width), **self._kern))
 
     # ------------------------------------------------------ exec cache --
-    def _compiled(self, name: str, fn, args):
-        """Shape-keyed AOT cache lookup; compiles on miss.
+    def _compiled(self, name: str, fn, args, *, scope: str = "engine"):
+        """Shape-keyed AOT cache lookup; compiles on miss, as the module
+        ``jit_<scope>_<stage>``: the cache name without its static width
+        suffix, so the name is the same on every run and carries no
+        shape.
 
         Thread-safe: the service's background warmup thread compiles
         concurrently with the exec thread, so a miss installs a pending
@@ -379,7 +391,9 @@ class ServingEngine:
         if isinstance(entry, _PendingCompile):
             if owner:
                 try:
-                    exe = jax.jit(fn).lower(*args).compile()
+                    stage = name.split(":")[0]
+                    exe = jax.jit(named(fn, f"{scope}_{stage}")
+                                  ).lower(*args).compile()
                 except BaseException as e:
                     with self._cache_lock:
                         self._cache.pop(key, None)
@@ -1131,7 +1145,7 @@ class SchedPrograms:
 
     def _run(self, name: str, fn, *args):
         a = tuple(jnp.asarray(x) for x in args)
-        exe = self.engine._compiled(name, fn, a)
+        exe = self.engine._compiled(name, fn, a, scope="sched")
         self.engine._m_dispatch.inc()
         # the span covers the *dispatch window* only (no added sync —
         # chunk advances stay async; gather/finalize sync in the caller)
@@ -1164,7 +1178,9 @@ class SchedPrograms:
         e = self.engine
         *rows, slen = self._run("sgather", self._gather_fn, e.offsets,
                                 e.pdoc, e.pimp, e.pscore, qt)
-        return tuple(rows), np.asarray(slen), None
+        with e.trace.span("sched.sync", prog="sgather"):
+            slen = np.asarray(slen)
+        return tuple(rows), slen, None
 
     def refill(self, state: SchedState, slot_idx: np.ndarray,
                rows) -> SchedState:
@@ -1200,7 +1216,9 @@ class SchedPrograms:
             r = self._run("finalize", self._final_fn, state.acc,
                           state.sdocs, state.s3, slot_idx, pvec, dvec,
                           qids, e.doc_len)
-        return _pad_ranked(np.asarray(r), e.cfg.rerank_depth)
+        with e.trace.span("sched.sync", prog="finalize"):
+            r = np.asarray(r)
+        return _pad_ranked(r, e.cfg.rerank_depth)
 
     def warmup(self, slots: int, query_len: int) -> int:
         """Compile all four programs.  Safe mid-flight: the dummy refill
@@ -1466,7 +1484,7 @@ class ShardedSchedPrograms(SchedPrograms):
         mesh = self.engine.mesh
         a = tuple(jax.device_put(jnp.asarray(x), NamedSharding(mesh, s))
                   for x, s in zip(args, self._arg_specs[name]))
-        exe = self.engine._compiled(name, fn, a)
+        exe = self.engine._compiled(name, fn, a, scope="sched")
         self.engine._m_dispatch.inc()
         with self.engine.trace.span("sched." + name):
             return exe(*a)
@@ -1510,7 +1528,8 @@ class ShardedSchedPrograms(SchedPrograms):
         e = self.engine
         *rows, meta = self._run("sgather", self._gather_fn, e.offsets,
                                 e.pdoc, e.pimp, e.pscore, qt)
-        m = np.asarray(meta)
+        with e.trace.span("sched.sync", prog="sgather"):
+            m = np.asarray(meta)
         slen, ovf, lend = m[:, 0], m[:, 1], m[:, 2:]
         worst = int(ovf.max()) if ovf.size else 0
         if worst > 0:

@@ -36,7 +36,8 @@ from repro.core import forest as forest_lib
 from repro.core import knobs as knobs_lib
 from repro.retrieval import gold, jass
 from repro.serving import bucketing
-from repro.serving.engine import ServingEngine, ShardedServingEngine
+from repro.serving.engine import (ServingEngine, ShardedServingEngine,
+                                  named)
 
 __all__ = ["ServingConfig", "RetrievalServer"]
 
@@ -207,8 +208,10 @@ class RetrievalServer:
                                                 depth)
             return jnp.min(jnp.abs(p0 - thresholds[None, :]), axis=1)
 
-        self._predict_fns[knob] = jax.jit(_predict)
-        self._margin_fns[knob] = jax.jit(_margin)
+        # stable module names on the device trace: jit_cascade_<knob>,
+        # jit_margin_<knob>
+        self._predict_fns[knob] = jax.jit(named(_predict, f"cascade_{knob}"))
+        self._margin_fns[knob] = jax.jit(named(_margin, f"margin_{knob}"))
         with self._swap_lock:
             self._live = {**self._live, knob: (node_params, thresholds)}
 

@@ -108,6 +108,9 @@ class ContinuousScheduler:
         # on tick/step/slot spans; not under _lock by the same
         # single-owner contract
         self._tick_id = 0
+        #: when the last working tick finished (scheduler clock); the
+        #: service's stall watchdog reads it from its own thread
+        self.t_last_tick = self.clock()
         self.bind_obs(NULL_OBS)
 
     def bind_obs(self, obs) -> None:
@@ -128,16 +131,24 @@ class ContinuousScheduler:
         0 means the scheduler is idle and the queue is empty."""
         t = self.clock() if now is None else now
         ev = self._finalize_step(t)
-        ev += self._refill_step(t)
-        ev += self._chunk_step(t)
+        ev += self._refill_step()
+        ev += self._chunk_step()
         if ev:
             # working ticks only: idle polls would flood the span ring
             # and make the deterministic tick count load-dependent
-            self.obs.trace.record("tick", t, self.clock(),
-                                  tick=self._tick_id, ev=ev)
+            t1 = self.clock()
+            self.obs.trace.record("tick", t, t1, tick=self._tick_id, ev=ev)
+            self.t_last_tick = t1
             self._m_ticks.inc()
             self._tick_id += 1
         return ev
+
+    @property
+    def device_ready(self) -> bool | None:
+        """Whether the last dispatched slot-table state has been computed
+        (None before the first admission).  Safe from any thread."""
+        state = self._state
+        return None if state is None else bool(state.acc.is_ready())
 
     @property
     def idle(self) -> bool:
@@ -168,7 +179,25 @@ class ContinuousScheduler:
             g = self._pop_group(t)
         if not g:
             return 0
-        t0 = self.clock()
+        with self.obs.trace.span("tick.finalize", tick=self._tick_id,
+                                 n=len(g)) as sp:
+            self._finalize_group(g, sp.t0)
+        with self._lock:
+            for s in g:
+                # pool rows the rerank actually scored for this slot vs
+                # the depth-free pool (k: the predicted pool width,
+                # clamped to the static pool; rho: the static depth)
+                full = (min(s.width, self.full_depth)
+                        if self.knob == "k" else self.full_depth)
+                self.n_rows_scored += min(s.depth, full)
+                self.n_rows_full += full
+                self.table.release(s)
+            self.n_finalize_calls += 1
+        return len(g)
+
+    def _finalize_group(self, g, t0: float) -> None:
+        """Stages 1b-3 for the retired group ``g``: resolve its futures
+        and report them (``t0``: when the finalize step began)."""
         pad = len(g)
         idx = np.full(self.grain, g[0].idx, np.int32)
         pvec = np.ones(self.grain, np.int32)
@@ -220,20 +249,6 @@ class ContinuousScheduler:
         if self.on_results is not None:
             self.on_results(reqs, results, t_done,
                             service_ms=(t_done - t0) * 1e3)
-        trace.record("tick.finalize", t0, self.clock(),
-                     tick=self._tick_id, n=len(g))
-        with self._lock:
-            for s in g:
-                # pool rows the rerank actually scored for this slot vs
-                # the depth-free pool (k: the predicted pool width,
-                # clamped to the static pool; rho: the static depth)
-                full = (min(s.width, self.full_depth)
-                        if self.knob == "k" else self.full_depth)
-                self.n_rows_scored += min(s.depth, full)
-                self.n_rows_full += full
-                self.table.release(s)
-            self.n_finalize_calls += 1
-        return len(g)
 
     def _pop_group(self, t: float):
         # caller holds the lock.  Fire on: a full grain of retirees; no
@@ -253,31 +268,30 @@ class ContinuousScheduler:
         return g
 
     # ------------------------------------------------------------ refill --
-    def _refill_step(self, t: float) -> int:
+    def _refill_step(self) -> int:
         ev = 0
+        trace = self.obs.trace
         while True:
             with self._lock:
                 free = self.table.n_free
             if free == 0:
                 break
             cand = self.queue.take_urgent(self.window)
+            # admission: the moment the requests left the pending set
+            t_admit = self.clock()
             cand = [r for r in cand if self._fits(r)]
             if not cand:
                 break
             n = min(free, self.grain, len(cand))
-            t0 = self.clock()
-            classes, ver = self._predict(cand)
-            t1 = self.clock()
-            predict_ms = (t1 - t0) * 1e3
-            self.obs.trace.record("predict", t0, t1,
-                                  tick=self._tick_id, n=len(cand))
-            keep, back = self._select(cand, classes, n)
-            if back.size:
-                self.queue.requeue([cand[i] for i in back])
-            self._admit([cand[i] for i in keep], classes[keep], ver,
-                        predict_ms, t)
-            self.obs.trace.record("tick.refill", t0, self.clock(),
-                                  tick=self._tick_id, n=len(keep))
+            with trace.span("tick.refill", tick=self._tick_id):
+                with trace.span("predict", tick=self._tick_id,
+                                n=len(cand)) as psp:
+                    classes, ver = self._predict(cand)
+                keep, back = self._select(cand, classes, n)
+                if back.size:
+                    self.queue.requeue([cand[i] for i in back])
+                self._admit([cand[i] for i in keep], classes[keep], ver,
+                            psp.dur_ms, t_admit)
             ev += 1
             if len(keep) < self.grain:
                 break                  # queue drained below a full grain
@@ -332,7 +346,7 @@ class ContinuousScheduler:
         return np.sort(keep), back
 
     def _admit(self, group, classes, ver, predict_ms: float,
-               t: float) -> None:
+               t_admit: float) -> None:
         if not group:
             return
         if self._state is None:
@@ -370,7 +384,7 @@ class ContinuousScheduler:
                                  if dclasses is not None else -1)
                 s.version = int(ver)
                 s.predict_ms = predict_ms
-                s.t_admit = t
+                s.t_admit = t_admit
                 s.pos = 0
                 s.chunks = 0
                 sl = int(slen[i])
@@ -392,14 +406,13 @@ class ContinuousScheduler:
                 self.n_admitted += 1
                 # the request's wait in the pending set (take_urgent
                 # bypasses batch formation, so the queue span lands here)
-                self.obs.trace.record("queue", r.t_submit, t, qid=s.qid,
-                                      slot=s.idx)
+                self.obs.trace.record("queue", r.t_submit, t_admit,
+                                      qid=s.qid, slot=s.idx)
                 if done:               # empty stream: retire immediately
-                    self._retire(s, t, occ)
+                    self._retire(s, t_admit, occ)
 
     # ------------------------------------------------------------- chunk --
-    def _chunk_step(self, t: float) -> int:
-        t0 = self.clock()
+    def _chunk_step(self) -> int:
         with self._lock:
             act = self.table.active()
             if not act:
@@ -413,24 +426,26 @@ class ContinuousScheduler:
                 pos[s.idx] = s.lpos if sharded else s.pos
                 end[s.idx] = s.end
             self.n_chunk_calls += 1
-        self._state = self.prog.chunk(self._state, pos, end)
-        with self._lock:
-            occ = self.table.n_occupied / self.slots
-            cp = self.prog.chunk_p
-            for s in act:
-                s.chunks += 1
-                if sharded:
-                    s.lpos = min(s.lpos + cp, s.lend)
-                    done = s.lpos >= s.lend
-                else:
-                    s.pos = min(s.pos + cp, s.end)
-                    done = s.pos >= s.end
-                if done:
-                    self._retire(s, t, occ)
-        # host-only recording: the chunk dispatch window (the sched.chunk
-        # span inside prog.chunk covers the dispatch itself)
-        self.obs.trace.record("tick.chunk", t0, self.clock(),
-                              tick=self._tick_id, n=len(act))
+        # the chunk step's window; the sched.chunk span inside prog.chunk
+        # covers the dispatch itself
+        with self.obs.trace.span("tick.chunk", tick=self._tick_id,
+                                 n=len(act)) as sp:
+            self._state = self.prog.chunk(self._state, pos, end)
+            with self._lock:
+                occ = self.table.n_occupied / self.slots
+                cp = self.prog.chunk_p
+                for s in act:
+                    s.chunks += 1
+                    if sharded:
+                        s.lpos = min(s.lpos + cp, s.lend)
+                        done = s.lpos >= s.lend
+                    else:
+                        s.pos = min(s.pos + cp, s.end)
+                        done = s.pos >= s.end
+                    if done:
+                        # stamped when this step began, which follows
+                        # every admission of the tick
+                        self._retire(s, sp.t0, occ)
         return 1
 
     def _retire(self, s, t: float, occupancy: float) -> None:

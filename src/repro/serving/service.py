@@ -31,6 +31,10 @@ request/response API:
   ``warmup_batch_sizes`` list, the policy watches the admission queue's
   padded-batch-size census and pre-compiles the most common shapes on a
   background thread.
+* **Stall watchdog** (continuous mode, observability on): a thread that
+  records a ``stall`` span whenever work is in flight and no working
+  tick has finished for ``STALL_S`` seconds, with the stacks of every
+  thread sampled when it is detected.
 
 ``step()`` runs one admission+dispatch cycle inline (no threads) — the
 deterministic mode tests and synchronous callers use.
@@ -43,8 +47,10 @@ import itertools
 import json
 import os
 import queue as queue_lib
+import sys
 import threading
 import time
+import traceback
 from typing import Protocol, runtime_checkable
 
 import numpy as np
@@ -54,7 +60,16 @@ from repro.serving.admission import AdmissionConfig, AdmissionQueue, Batch
 
 __all__ = ["Backend", "EngineBackend", "ShardedEngineBackend",
            "ContinuousBackend", "FunnelBackend", "WarmupPolicy",
-           "RetrievalService"]
+           "RetrievalService", "STALL_S"]
+
+#: a stall: work in flight and no working tick finished for this long
+#: (half a 1 s deadline, about three ticks of a 64-slot table on a v5e)
+STALL_S = 0.5
+#: the stall watchdog's sleep; its overshoots say whether the whole
+#: process stood still
+_WATCH_PERIOD_S = 0.05
+#: frames kept per thread in a stall's ``stacks``
+_STACK_DEPTH = 8
 
 
 # ------------------------------------------------------------- backends --
@@ -602,6 +617,8 @@ class RetrievalService:
         self._m_missed = self.obs.metrics.counter(
             "service.deadline_missed")
         self._m_cancelled = self.obs.metrics.counter("service.cancelled")
+        if self._sched is not None:
+            self._m_stalls = self.obs.metrics.counter("service.stalls")
 
     # ------------------------------------------------------------ submit --
     def submit(self, payload, deadline_ms: float | None = None):
@@ -877,6 +894,67 @@ class RetrievalService:
             except Exception:          # noqa: BLE001 — stay alive; the
                 pass                   # policy records per-shape failures
 
+    def _watch_loop(self) -> None:
+        """Stall watchdog (continuous mode, observability on).  Watches
+        the stretch since the last working tick finished (or since work
+        arrived, if later); once it passes ``STALL_S`` with work in
+        flight it samples every thread's stack, and when the next
+        working tick finishes (or the work drains, or the service stops)
+        it records the stretch as one ``stall`` span and counts it in
+        ``service.stalls``.  A stretch that ended before the watchdog
+        could wake (the whole process stood still) is still recorded,
+        with the stacks of the moment it woke."""
+        sched, trace = self._sched, self.obs.trace
+        clock = sched.clock
+        since = None           # start of the watched stretch
+        stall = None           # its attrs, once it passed STALL_S
+        lag_peak = 0.0         # largest sleep overshoot in the stretch
+        t_wake = clock()
+        while not self._stop.wait(_WATCH_PERIOD_S):
+            now = clock()
+            lag_ms = max(0.0, now - t_wake - _WATCH_PERIOD_S) * 1e3
+            t_wake = now
+            lag_peak = max(lag_peak, lag_ms)
+            last = sched.t_last_tick
+            busy = self.outstanding > 0
+            if since is not None and (last > since or not busy):
+                end = last if last > since else now
+                if stall is None and end - since >= STALL_S:
+                    stall = self._stall_attrs(lag_peak)
+                if stall is not None:
+                    stall["watchdog_lag_ms"] = lag_peak
+                    trace.record("stall", since, end, **stall)
+                    self._m_stalls.inc()
+                since, stall, lag_peak = (last if busy else None), None, 0.0
+            elif busy and since is None:
+                since, lag_peak = max(last, now), 0.0
+            elif stall is None and busy and now - since >= STALL_S:
+                stall = self._stall_attrs(lag_peak)
+        if stall is not None:
+            stall["watchdog_lag_ms"] = lag_peak
+            trace.record("stall", since, clock(), **stall)
+            self._m_stalls.inc()
+
+    def _stall_attrs(self, lag_ms: float) -> dict:
+        """What the process is doing: the innermost ``_STACK_DEPTH``
+        frames of every other thread (the tick thread first, innermost
+        frame first), the watchdog's largest sleep overshoot so far, and
+        whether the last dispatched slot-table state is computed."""
+        names = {t.ident: t.name for t in threading.enumerate()}
+        me = threading.get_ident()
+        stacks = {}
+        for ident, frame in sorted(sys._current_frames().items(),
+                                   key=lambda kv: names.get(kv[0])
+                                   != "svc-sched"):
+            if ident == me:
+                continue
+            stacks[names.get(ident, str(ident))] = [
+                f"{f.name} ({os.path.basename(f.filename)}:{f.lineno})"
+                for f in reversed(traceback.extract_stack(
+                    frame, limit=_STACK_DEPTH))]
+        return {"stacks": stacks, "watchdog_lag_ms": lag_ms,
+                "device_ready": self._sched.device_ready}
+
     def start(self) -> "RetrievalService":
         if self._threads:
             return self
@@ -890,6 +968,10 @@ class RetrievalService:
                 threading.Thread(target=self._warmup_loop,
                                  name="svc-warmup", daemon=True),
             ]
+            if self.obs.enabled:
+                self._threads.append(threading.Thread(
+                    target=self._watch_loop, name="svc-watchdog",
+                    daemon=True))
         else:
             self._threads = [
                 threading.Thread(target=self._admit_loop,

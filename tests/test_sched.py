@@ -349,3 +349,35 @@ def test_retirement_trail_reaches_telemetry_ring(small_system):
         assert 0 <= r.chunks_executed <= r.chunks_max
         assert 0.0 < r.slot_occupancy <= 1.0
         assert r.pred_class >= 0 and r.ranked is not None
+
+
+def test_admission_stamped_when_request_leaves_pending(small_system):
+    """Under churn every continuous result has queue_ms >= 0: admission
+    is stamped when the refill takes the request from the pending set,
+    not at the start of the tick that admits it.  Requests arriving
+    while a tick finalizes are taken by that tick's refill, which is
+    where the tick-start stamp read below zero."""
+    server, _ = _server(small_system)
+    backend = ContinuousBackend(server, slots=8, grain=4)
+    svc = RetrievalService(backend)
+    backend.scheduler.warmup()
+    prog = backend.scheduler.prog
+    finalize = prog.finalize
+    qt = list(small_system.queries.terms)
+    futs = svc.submit_many(qt[:8], deadline_ms=1e9)
+    waiting = qt[8:40]
+
+    def finalize_amid_arrivals(*a):
+        out = finalize(*a)
+        if waiting:                    # a wave lands mid-tick
+            futs.extend(svc.submit_many(waiting[:4], deadline_ms=1e9))
+            del waiting[:4]
+        return out
+
+    prog.finalize = finalize_amid_arrivals
+    _drain(svc)
+    results = [f.result() for f in futs]
+    assert len(results) == 40
+    for r in results:
+        assert r["queue_ms"] >= 0.0, r["queue_ms"]
+        assert r["total_ms"] >= r["queue_ms"] + r["service_ms"] - 1e-6

@@ -372,3 +372,59 @@ def test_serve_batch_reports_stage_timings(small_system):
                 "rerank_ms", "total_ms"):
         assert key in t and t[key] >= 0.0
     assert t["total_ms"] >= t["gather_ms"]
+
+
+# ---------------------------------------------------------- module names --
+
+def _module_name(exe) -> str:
+    """The HLO module name an executable was compiled under."""
+    return exe.as_text().split(None, 2)[1].rstrip(",")
+
+
+@pytest.mark.parametrize("knob", ["rho", "k"])
+def test_every_program_lowers_under_a_stable_module_name(small_system,
+                                                         knob):
+    """Device traces name programs by module: every engine stage,
+    scheduler program and cascade predict lowers as jit_<scope>_<stage>,
+    never as the anonymous jit__unknown of a bare functools.partial."""
+    from repro.core import cascade as cascade_lib
+    from repro.serving.engine import SchedPrograms
+
+    sys_ = small_system
+    cuts = sys_.k_cutoffs if knob == "k" else sys_.rho_cutoffs
+    labels = np.arange(sys_.queries.n_queries) % (len(cuts) + 1)
+    casc = cascade_lib.train_cascade(
+        sys_.features, labels, n_cutoffs=len(cuts),
+        forest_kwargs=dict(n_trees=2, max_depth=2))
+    server = serve_lib.RetrievalServer(sys_.index, casc,
+                                       serve_lib.ServingConfig(
+                                           knob=knob, cutoffs=cuts,
+                                           rerank_depth=30,
+                                           stream_cap=sys_.cfg.stream_cap))
+    qt = sys_.queries.terms[:8]
+    server.serve_batch(qt)
+    if knob == "k":                      # a pool wider than the grid
+        server.serve_fixed(qt, server.engine.max_k + 8)
+    SchedPrograms.for_engine(server.engine, grain=8).warmup(
+        8, qt.shape[1])
+    names = {key[0]: _module_name(exe)
+             for key, exe in server.engine._cache.items()}
+    stages = {"gather", "stage1", "stage2", "rerank"}
+    expect = {**{s: f"jit_engine_{s}" for s in stages},
+              **{p: f"jit_sched_{p}"
+                 for p in ("sgather", "refill", "chunk", "finalize")}}
+    if knob == "k":                      # the k stage 1 is keyed by width
+        del expect["stage1"]
+        for w in (server.engine.max_k, server.engine.max_k + 8):
+            expect[f"stage1:{w}"] = "jit_engine_stage1"
+    assert names == expect
+
+    node_params, thresholds = server._live[knob]
+    args = (node_params, thresholds,
+            np.full((8, qt.shape[1]), -1, np.int32),
+            (server.stats, server.ctf, server.df))
+    for fns, prefix in ((server._predict_fns, "cascade"),
+                        (server._margin_fns, "margin")):
+        text = fns[knob].lower(*args).as_text()
+        assert text.startswith(f"module @jit_{prefix}_{knob} "), \
+            text.splitlines()[0]

@@ -153,3 +153,32 @@ def test_continuous_backend_accepts_model_only_sharded_engine(tiny_system):
     np.testing.assert_array_equal(
         np.stack([r["ranked"] for r in res]), ref)
     assert service.backend.scheduler.stats()["sharded"] is True
+
+
+def test_sharded_programs_lower_under_stable_module_names(tiny_system):
+    """The sharded engine stages and ShardedSchedPrograms lower as
+    jit_engine_<stage> / jit_sched_<program>, as the unsharded ones do."""
+    import numpy as np
+
+    from repro.launch.mesh import make_smoke_mesh
+    from repro.serving import pipeline as sp
+    from repro.serving.service import ContinuousBackend, RetrievalService
+
+    cuts = tiny_system.k_cutoffs
+    cfg = sp.ServingConfig(knob="k", cutoffs=cuts, rerank_depth=30,
+                           stream_cap=tiny_system.cfg.stream_cap)
+    srv = sp.RetrievalServer(tiny_system.index, None, cfg,
+                             mesh=make_smoke_mesh())
+    srv.predict_classes = (
+        lambda qt: np.zeros(len(qt), np.int64) + len(cuts))
+    qt = tiny_system.queries.terms[:8]
+    srv.engine.serve(qt, srv.params_of(srv.predict_classes(qt)))
+    RetrievalService(ContinuousBackend(srv, slots=8, grain=4)).serve_all(
+        list(qt), deadline_ms=1e6)
+    names = {key[0]: exe.as_text().split(None, 2)[1].rstrip(",")
+             for key, exe in srv.engine._cache.items()}
+    stages = ("gather", "stage1", "allgather", "stage2", "merge",
+              "rerank")
+    assert names == {**{s: f"jit_engine_{s}" for s in stages},
+                     **{p: f"jit_sched_{p}" for p in
+                        ("sgather", "refill", "chunk", "finalize")}}
