@@ -25,7 +25,9 @@ from repro.retrieval import jass
 __all__ = [
     "second_stage_scores",
     "second_stage_mix",
+    "pool_stage2_scores",
     "rerank_pool",
+    "rerank_scored",
     "gold_run_k",
     "candidate_run_k",
     "gold_run_rho",
@@ -59,8 +61,11 @@ def second_stage_mix(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
     per-query min/max over the *full* doc axis.  Split out so the
     mesh-sharded engine can compute bounds with pmin/pmax collectives over
     its doc shards and still run bit-identical mixing arithmetic on each
-    local (Q, width) block.  ``doc_ids`` are the global ids of the block's
-    columns (the noise hash keys on them).
+    local (Q, width) block, and so ``pool_stage2_scores`` can mix only a
+    (Q, K) block of pool members.  ``doc_ids`` are the global ids of the
+    block's columns (the noise hash keys on them) and ``doc_len`` their
+    lengths: both (width,) where every row shares its columns, or (Q, K)
+    where each row has its own.
 
     Each weighted term is rounded to a multiple of ``2**-20`` before the
     terms are added, so the sum (below 2) is exact in float32.  Without
@@ -78,11 +83,11 @@ def second_stage_mix(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
 
     (b_lo, b_hi), (l_lo, l_hi), (t_lo, t_hi) = bounds
     prior = 1.0 / jnp.log(2.0 + doc_len.astype(jnp.float32))
-    noise = jax.vmap(lambda q: _hash_noise(doc_ids, q, seed))(qids)
+    noise = _hash_noise(doc_ids, qids[:, None], seed)
     return (term(0.45, norm(acc_bm25, b_lo, b_hi))
             + term(0.25, norm(acc_lm, l_lo, l_hi))
             + term(0.15, norm(acc_tfidf, t_lo, t_hi))
-            + term(0.05, prior[None, :]) + term(noise_weight, noise))
+            + term(0.05, prior) + term(noise_weight, noise))
 
 
 def second_stage_scores(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
@@ -95,6 +100,11 @@ def second_stage_scores(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
     The mixture + interaction noise makes the induced ranking correlated
     with — but distinct from — any single stage-1 scorer, mirroring the
     gold run's relationship to the BM25 candidate run in the paper.
+
+    The oracle form: labelling (``core.experiment``), the per-bucket
+    reference path (``pipeline.serve_batch_reference``) and the sharded
+    engine score every doc; the single-chip serving programs score only
+    the pool with ``pool_stage2_scores``, which equals this bit for bit.
     """
     n_docs = acc_bm25.shape[-1]
 
@@ -109,22 +119,75 @@ def second_stage_scores(acc_bm25: jnp.ndarray, acc_lm: jnp.ndarray,
         seed=seed, noise_weight=noise_weight)
 
 
-@functools.partial(jax.jit, static_argnames=("depth",))
-def rerank_pool(stage2: jnp.ndarray, pool: jnp.ndarray, depth: int) -> jnp.ndarray:
-    """Rank the docs of ``pool`` (Q, P; -1 padded) by second-stage score.
+def pool_stage2_scores(sdocs: jnp.ndarray, s3: jnp.ndarray,
+                       pool: jnp.ndarray, doc_len: jnp.ndarray,
+                       qids: jnp.ndarray, *, n_docs: int, cap: int,
+                       seed: int = 11,
+                       noise_weight: float = 0.35) -> jnp.ndarray:
+    """Second-stage scores of the pool's docs alone: (Q, K).
 
-    Returns (Q, depth) doc ids.  Only pool members are eligible — this is
-    the restriction semantics used for labeling k.
+    Equal bit for bit to ``second_stage_scores`` over
+    ``jass.scorer_accumulators(sdocs, s3, n_docs)``, read at each row's
+    pool members (``-1`` entries score as doc 0 and are for the caller to
+    mask), with no ``n_docs``-wide array.  ``sdocs``/``s3`` are the
+    gathered score postings (``jass.gather_score_streams`` at ``cap``).
+
+    The per-doc sums come from ``jass.scorer_sums``.  The bounds stay the
+    min/max over *all* documents: a doc no posting touched holds 0, so
+    they are the min/max of the touched docs' sums, with 0 joining them
+    whenever fewer than ``n_docs`` docs were touched.  A pool member's
+    sums are found by binary search in the row's sorted doc ids; a
+    member with no score posting holds 0.
+    """
+    docs, head, sums = jass.scorer_sums(sdocs, s3, n_docs, cap)
+    untouched = jnp.sum(head, axis=1, keepdims=True) < n_docs
+
+    def bound(x):
+        lo = jnp.min(jnp.where(head, x, jnp.inf), axis=1, keepdims=True)
+        hi = jnp.max(jnp.where(head, x, -jnp.inf), axis=1, keepdims=True)
+        return (jnp.where(untouched, jnp.minimum(lo, 0.0), lo),
+                jnp.where(untouched, jnp.maximum(hi, 0.0), hi))
+
+    p = jnp.clip(pool, 0)
+    pos = jax.vmap(lambda d, v: jnp.searchsorted(
+        d, v, method="scan_unrolled"))(docs, p)
+    pos = jnp.minimum(pos, docs.shape[1] - 1)
+    hit = jnp.take_along_axis(docs, pos, axis=1) == p
+    acc = [jnp.where(hit, jnp.take_along_axis(x, pos, axis=1), 0.0)
+           for x in sums]
+    return second_stage_mix(*acc, tuple(bound(x) for x in sums),
+                            doc_len[p], qids, p, seed=seed,
+                            noise_weight=noise_weight)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",))
+def rerank_scored(scores: jnp.ndarray, pool: jnp.ndarray,
+                  depth: int) -> jnp.ndarray:
+    """Rank the docs of ``pool`` (Q, P; -1 padded) by their second-stage
+    ``scores`` (Q, P), ties by ascending doc id.
+
+    Returns (Q, depth) doc ids, -1 where the pool runs out.
     """
 
-    def one(scores, p):
-        valid = p >= 0
-        s = jnp.where(valid, scores[jnp.clip(p, 0)], -jnp.inf)
+    def one(sc, p):
+        s = jnp.where(p >= 0, sc, -jnp.inf)
         order = jnp.lexsort((p, -s))
         top = order[:depth]
         return jnp.where(s[top] > -jnp.inf, p[top], -1).astype(jnp.int32)
 
-    return jax.vmap(one)(stage2, pool)
+    return jax.vmap(one)(scores, pool)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",))
+def rerank_pool(stage2: jnp.ndarray, pool: jnp.ndarray, depth: int) -> jnp.ndarray:
+    """Rank the docs of ``pool`` (Q, P; -1 padded) by the dense
+    second-stage scores ``stage2`` (Q, n_docs).
+
+    Returns (Q, depth) doc ids.  Only pool members are eligible — this is
+    the restriction semantics used for labeling k.
+    """
+    scores = jnp.take_along_axis(stage2, jnp.clip(pool, 0), axis=1)
+    return rerank_scored(scores, pool, depth)
 
 
 def gold_run_k(stage2, deep_pool, depth: int) -> jnp.ndarray:

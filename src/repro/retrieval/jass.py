@@ -161,7 +161,12 @@ def gather_score_streams(offsets: jnp.ndarray, postings_doc: jnp.ndarray,
 def scorer_accumulators(docs: jnp.ndarray, scores3: jnp.ndarray,
                         n_docs: int):
     """Dense per-scorer accumulators: (Q, n_docs) x3 from gathered
-    postings.  These are the stage-2 features of the reranker stand-in."""
+    postings.  These are the stage-2 features of the reranker stand-in.
+
+    The oracle form, for paths that score every doc (labelling, the
+    per-bucket reference, the sharded engine); the single-chip serving
+    programs use ``scorer_sums``, which gives the same sums for touched
+    docs without the (Q, n_docs, 3) block."""
 
     def one(d, s):
         safe = jnp.clip(d, 0)
@@ -171,3 +176,52 @@ def scorer_accumulators(docs: jnp.ndarray, scores3: jnp.ndarray,
 
     acc = jax.vmap(one)(docs, scores3)       # (Q, n_docs, 3)
     return acc[..., 0], acc[..., 1], acc[..., 2]
+
+
+def scorer_sums(docs: jnp.ndarray, scores3: jnp.ndarray, n_docs: int,
+                cap: int):
+    """Per-doc sums of gathered score postings, one per distinct doc: the
+    sparse form of ``scorer_accumulators``, with no ``n_docs``-wide array.
+
+    ``docs`` (Q, L*cap) holds query term ``t``'s postings in columns
+    ``[t*cap, (t+1)*cap)``, -1 padded (``gather_score_streams``); a doc
+    appears at most once per term.  Each row is sorted by the unique key
+    ``doc*L + t`` (padding last), so a doc's entries lie side by side in
+    term order, and ``L - 1`` shifted masked adds sum them at the first
+    of them as ``((0 + v_0) + v_1) + ...``: the order in which the
+    scatter-add of ``scorer_accumulators`` meets them, so the sums are
+    bit-identical.
+
+    Returns (sdocs (Q, L*cap) int32 ascending, ``n_docs`` on padding;
+    head (Q, L*cap) bool, true at each doc's first entry; sums, one
+    (Q, L*cap) float32 per scorer, each doc's sums at its head).  The
+    scores travel through the sort as payloads, one row per scorer: a
+    gather of (Q, L*cap, 3) rows would pad the 3 to a full lane tile on
+    a TPU.
+    """
+    q, n = docs.shape
+    n_terms = n // cap
+    if n_docs * n_terms >= 2**31:
+        raise ValueError(
+            f"n_docs * query terms = {n_docs} * {n_terms} does not fit "
+            "the int32 sort key of scorer_sums")
+    term = jnp.arange(n, dtype=jnp.int32) // cap
+    key = jnp.where(docs >= 0, docs * n_terms + term[None, :],
+                    n_docs * n_terms)
+    key, *vals = jax.lax.sort(
+        (key,) + tuple(scores3[..., k] for k in range(scores3.shape[-1])),
+        dimension=1, is_stable=False, num_keys=1)
+    sdocs = key // n_terms
+    # one step per later term: entry i takes entry i+k's value while
+    # both hold the same doc (a run is at most n_terms long)
+    fill = ((0, 0), (0, n_terms - 1))
+    dpad = jnp.pad(sdocs, fill, constant_values=-1)
+    vpad = [jnp.pad(v, fill) for v in vals]
+    sums = list(vals)
+    for k in range(1, n_terms):
+        same = dpad[:, k:k + n] == sdocs
+        sums = [jnp.where(same, s + vp[:, k:k + n], s)
+                for s, vp in zip(sums, vpad)]
+    first = jnp.concatenate(
+        [jnp.ones((q, 1), bool), sdocs[:, 1:] != sdocs[:, :-1]], axis=1)
+    return sdocs, first & (sdocs < n_docs), tuple(sums)

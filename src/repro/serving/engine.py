@@ -15,8 +15,9 @@ This engine issues a *constant* number of dispatches per batch:
              one executable) and select the candidate pool at a static
              max-k, masked per query by a traced pool-width vector (all k
              buckets in one executable)
-  stage2   — dense per-scorer accumulators + second-stage scores
-  rerank   — final list from the per-query pool
+  stage2   — second-stage scores of the pool's docs, from the query's
+             gathered score postings (no n_docs-wide array)
+  rerank   — final list from the per-query pool and its scores
 
 The predicted parameter enters every stage as *data* (a traced vector),
 never as a static argument, so the executable count is O(1) per padded
@@ -161,13 +162,8 @@ def _stage1_k(ds, im, seg_lo, seg_hi, k_vec, *, n_docs: int, max_k: int,
     return jnp.where(keep, pool, -1)
 
 
-def _stage2(sdocs, s3, doc_len, qids, *, n_docs: int):
-    a_bm25, a_lm, a_tfidf = jass.scorer_accumulators(sdocs, s3, n_docs)
-    return gold.second_stage_scores(a_bm25, a_lm, a_tfidf, doc_len, qids)
-
-
-def _stage_rerank(stage2, pool, *, depth: int):
-    return gold.rerank_pool(stage2, pool, depth)
+def _stage_rerank(scores, pool, *, depth: int):
+    return gold.rerank_scored(scores, pool, depth)
 
 
 def _depth_mask(pool, depth_vec):
@@ -182,11 +178,11 @@ def _depth_mask(pool, depth_vec):
     return jnp.where(keep, pool, -1)
 
 
-def _stage_rerank_dyn(stage2, pool, depth_vec, *, depth: int):
+def _stage_rerank_dyn(scores, pool, depth_vec, *, depth: int):
     """``_stage_rerank`` with a traced per-query reranking depth: the
     third knob.  Static shapes are identical to the depth-free stage
     (one executable per padded shape; the depth enters as data)."""
-    return gold.rerank_pool(stage2, _depth_mask(pool, depth_vec), depth)
+    return gold.rerank_scored(scores, _depth_mask(pool, depth_vec), depth)
 
 
 # ----------------------------------------------------- scheduler stages --
@@ -257,7 +253,7 @@ def _sched_chunk(ds_b, im_b, lo_b, hi_b, acc, pos, end, *, chunk_p: int,
 
 
 def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
-                        depth: int, n_docs: int, route: str,
+                        depth: int, n_docs: int, cap: int, route: str,
                         interpret: bool):
     """Stages 1b-3 for a retiring group: pool selection over the finished
     accumulator rows, then stage-2 + rerank exactly as the batch path
@@ -269,22 +265,22 @@ def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
     rows = acc[slot_idx]
     pool = topk_lib.select_pool(rows, depth, route=route,
                                 interpret=interpret)
-    stage2 = _stage2(sd_b[slot_idx], s3_b[slot_idx], doc_len, qids,
-                     n_docs=n_docs)
-    return gold.rerank_pool(stage2, _depth_mask(pool, dvec), depth)
+    scores = gold.pool_stage2_scores(sd_b[slot_idx], s3_b[slot_idx], pool,
+                                     doc_len, qids, n_docs=n_docs, cap=cap)
+    return gold.rerank_scored(scores, _depth_mask(pool, dvec), depth)
 
 
 def _sched_finalize_k(acc, sd_b, s3_b, slot_idx, k_vec, dvec, qids,
                       doc_len, *, depth: int, max_k: int, n_docs: int,
-                      route: str, interpret: bool):
+                      cap: int, route: str, interpret: bool):
     rows = acc[slot_idx]
     pool = topk_lib.select_pool(rows, max_k, route=route,
                                 interpret=interpret)
     keep = jnp.arange(pool.shape[-1])[None, :] < k_vec[:, None]
     pool = jnp.where(keep, pool, -1)
-    stage2 = _stage2(sd_b[slot_idx], s3_b[slot_idx], doc_len, qids,
-                     n_docs=n_docs)
-    return gold.rerank_pool(stage2, _depth_mask(pool, dvec), depth)
+    scores = gold.pool_stage2_scores(sd_b[slot_idx], s3_b[slot_idx], pool,
+                                     doc_len, qids, n_docs=n_docs, cap=cap)
+    return gold.rerank_scored(scores, _depth_mask(pool, dvec), depth)
 
 
 class ServingEngine:
@@ -340,7 +336,9 @@ class ServingEngine:
                                          block_p=self.block_p,
                                          n_docs=self.n_docs,
                                          with_bounds=self.use_kernel)
-        self._stage2 = functools.partial(_stage2, n_docs=self.n_docs)
+        self._stage2 = functools.partial(gold.pool_stage2_scores,
+                                         n_docs=self.n_docs,
+                                         cap=cfg.stream_cap)
         self._rerank = functools.partial(_stage_rerank,
                                          depth=cfg.rerank_depth)
         self._rerank_dyn = functools.partial(_stage_rerank_dyn,
@@ -478,7 +476,7 @@ class ServingEngine:
         pool = timed("stage1_ms", s1_name, s1_fn, ds, im, seg_lo, seg_hi,
                      pv)
         stage2 = timed("stage2_ms", "stage2", self._stage2,
-                       sdocs, s3, self.doc_len, qids)
+                       sdocs, s3, pool, self.doc_len, qids)
         if depth_vec is None:
             ranked = timed("rerank_ms", "rerank", self._rerank, stage2,
                            pool)
@@ -726,14 +724,7 @@ def _sh_rerank(stage2, pool, *, axis: str, width: int, depth: int):
                   jnp.take_along_axis(
                       stage2, jnp.clip(pool - lo, 0, width - 1), axis=1),
                   -jnp.inf)
-    s = jax.lax.pmax(s, axis)
-
-    def one(sc, p):
-        order = jnp.lexsort((p, -sc))
-        top = order[:depth]
-        return jnp.where(sc[top] > -jnp.inf, p[top], -1).astype(jnp.int32)
-
-    return jax.vmap(one)(s, pool)
+    return gold.rerank_scored(jax.lax.pmax(s, axis), pool, depth)
 
 
 def _sh_rerank_dyn(stage2, pool, depth_vec, *, axis: str, width: int,
@@ -1134,7 +1125,8 @@ class SchedPrograms:
         route = engine.topk_route(cfg.depth_pool_width)
         engine.topk_routes["finalize"] = route
         common = dict(depth=cfg.rerank_depth, n_docs=engine.n_docs,
-                      route=route, interpret=engine.interpret)
+                      cap=cfg.stream_cap, route=route,
+                      interpret=engine.interpret)
         if cfg.knob == "rho":
             self._final_fn = functools.partial(_sched_finalize_rho,
                                                **common)

@@ -1,5 +1,7 @@
 """Retrieval substrate: index vs brute force, JASS semantics, gold runs."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -206,3 +208,96 @@ def test_second_stage_scores_identical_eager_and_jitted():
     eager = gold.second_stage_scores(*accs, doc_len, qids)
     jitted = jax.jit(gold.second_stage_scores)(*accs, doc_len, qids)
     np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))
+
+
+def _score_postings(r, *, q, n_terms, cap, n_docs, span, pad, lm_neg):
+    """Gathered score postings as ``gather_score_streams`` lays them out:
+    term t's postings in columns [t*cap, (t+1)*cap), a doc at most once
+    per term, -1 padded at the end of a term's segment; docs drawn from
+    the first ``span`` ids, so the terms of a query share documents."""
+    docs = np.full((q, n_terms, cap), -1, np.int32)
+    for i in range(q):
+        for t in range(n_terms):
+            n = cap - (r.integers(0, cap // 2) if pad else 0)
+            if pad and t == n_terms - 1:
+                n = 0                               # a padded query slot
+            docs[i, t, :n] = r.choice(span, n, replace=False)
+    s3 = np.stack([r.random((q, n_terms, cap)) * 9,           # bm25 > 0
+                   -r.random((q, n_terms, cap)) * 4 if lm_neg
+                   else r.normal(size=(q, n_terms, cap)),
+                   r.random((q, n_terms, cap)) * 3], axis=-1)
+    s3 = np.where(docs[..., None] >= 0, s3, 0.0).astype(np.float32)
+    return docs.reshape(q, -1), s3.reshape(q, n_terms * cap, 3)
+
+
+@pytest.mark.parametrize("case", [
+    dict(n_docs=300, span=40, pad=False, lm_neg=False, k=20),  # shared docs
+    dict(n_docs=300, span=40, pad=True, lm_neg=False, k=20),   # -1 padding
+    dict(n_docs=300, span=40, pad=False, lm_neg=True, k=20),   # LM sums < 0
+    dict(n_docs=300, span=300, pad=True, lm_neg=False, k=60),  # unscored pool
+    dict(n_docs=16, span=16, pad=False, lm_neg=False, k=12),   # all touched
+    dict(n_docs=300, span=120, pad=True, lm_neg=True, k=300),  # max_k wide
+], ids=["shared", "padding", "lm_negative", "unscored_pool",
+        "all_touched", "k_pool"])
+def test_pool_stage2_equals_dense(case):
+    """Stage 2 over the pool alone gives the dense stage-2 scores at the
+    pool's docs bit for bit, and the same reranked lists, whatever the
+    postings hold: docs under several terms, padding, docs with no score
+    posting, negative sums, and every doc touched (0 outside the bounds)."""
+    r = np.random.default_rng(31)
+    q, n_terms, cap, n_docs = 6, 4, 16, case["n_docs"]
+    sdocs, s3 = _score_postings(r, q=q, n_terms=n_terms, cap=cap,
+                                n_docs=n_docs, span=case["span"],
+                                pad=case["pad"], lm_neg=case["lm_neg"])
+    touched = [len(set(row[row >= 0].tolist())) for row in sdocs]
+    if case["span"] == n_docs and not case["pad"]:
+        assert min(touched) == n_docs      # the bounds leave out 0
+    pool = np.stack([r.choice(n_docs, case["k"], replace=False)
+                     for _ in range(q)]).astype(np.int32)
+    if case["pad"]:
+        pool[:, case["k"] // 2:] = -1
+        pool[-1] = -1                      # an empty pool
+    doc_len = jnp.asarray(r.integers(5, 300, n_docs).astype(np.int32))
+    qids = jnp.arange(100, 100 + q, dtype=jnp.int32)
+    args = (jnp.asarray(sdocs), jnp.asarray(s3))
+
+    @jax.jit
+    def dense(sd, sc):
+        return gold.second_stage_scores(
+            *jass.scorer_accumulators(sd, sc, n_docs), doc_len, qids)
+
+    sparse = jax.jit(functools.partial(gold.pool_stage2_scores,
+                                       n_docs=n_docs, cap=cap))
+    full = np.asarray(dense(*args))
+    got = np.asarray(sparse(*args, jnp.asarray(pool), doc_len, qids))
+    want = np.take_along_axis(full, np.clip(pool, 0, None), axis=1)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(gold.rerank_scored(got, pool, 30)),
+        np.asarray(gold.rerank_pool(full, pool, 30)))
+
+
+def test_second_stage_mix_shared_columns_equal_per_row():
+    """The mixture over a block whose rows share their columns (the dense
+    and sharded callers: 1-D ids and lengths) equals the per-row form
+    (the pool callers: (Q, K) ids and lengths) bit for bit."""
+    r = np.random.default_rng(4)
+    q, w = 5, 700
+    accs = [jnp.asarray(r.normal(size=(q, w)).astype(np.float32))
+            for _ in range(3)]
+    bounds = tuple((jnp.min(a, axis=1, keepdims=True),
+                    jnp.max(a, axis=1, keepdims=True)) for a in accs)
+    ids = jnp.asarray(r.integers(0, 10**6, w).astype(np.int32))
+    dl = jnp.asarray(r.integers(5, 300, w).astype(np.int32))
+    qids = jnp.arange(q, dtype=jnp.int32)
+    shared = gold.second_stage_mix(*accs, bounds, dl, qids, ids)
+    per_row = gold.second_stage_mix(
+        *accs, bounds, jnp.broadcast_to(dl, (q, w)), qids,
+        jnp.broadcast_to(ids, (q, w)))
+    np.testing.assert_array_equal(np.asarray(shared), np.asarray(per_row))
+
+
+def test_scorer_sums_refuses_an_overflowing_key():
+    docs = jnp.zeros((1, 4 * 8), jnp.int32)
+    with pytest.raises(ValueError, match="int32 sort key"):
+        jass.scorer_sums(docs, jnp.zeros((1, 32, 3)), 2**29, 8)

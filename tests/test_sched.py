@@ -57,17 +57,30 @@ def _drain(svc):
 
 # ------------------------------------------------- churn bit-identity --
 
+def _with_shared_docs(sys_, qt):
+    """``qt`` plus one query whose terms share documents: the two terms
+    with the longest postings lists, the first of them twice."""
+    a, b = np.argsort(-np.diff(sys_.index.offsets), kind="stable")[:2]
+    row = np.full((1, qt.shape[1]), -1, qt.dtype)
+    row[0, :3] = (a, b, a)
+    return np.concatenate([qt, row])
+
+
 @pytest.mark.parametrize("knob", ["rho", "k"])
 def test_churn_bit_identity_every_bucket(small_system, knob):
     """Results under slot churn are bit-identical to one batch-once
-    ``engine.serve`` of the same stream — with every class bucket of the
-    cutoff grid represented in the mix."""
+    ``engine.serve`` of the same stream, and both to the per-bucket
+    reference with its dense stage 2 — with every class bucket of the
+    cutoff grid represented in the mix and a query whose terms share
+    documents."""
     server, _ = _server(small_system, knob)
-    qt = small_system.queries.terms[:40]
+    qt = _with_shared_docs(small_system, small_system.queries.terms[:40])
     classes = np.asarray(server.predict_classes(qt))
     n_cls = len(server.cfg.cutoffs) + 1
     assert set(classes.tolist()) == set(range(n_cls))  # all buckets hit
     ranked_ref, _ = server.engine.serve(qt, server.params_of(classes))
+    np.testing.assert_array_equal(
+        ranked_ref, server.serve_batch_reference(qt)["ranked"])
 
     backend = ContinuousBackend(server, slots=16, grain=4, window=8)
     svc = RetrievalService(backend)
@@ -78,7 +91,7 @@ def test_churn_bit_identity_every_bucket(small_system, knob):
         assert res["chunks_executed"] <= res["chunks_max"]
         assert 0.0 < res["slot_occupancy"] <= 1.0
     sch = backend.scheduler.stats()
-    assert sch["n_admitted"] == sch["n_retired"] == 40
+    assert sch["n_admitted"] == sch["n_retired"] == len(qt)
     if knob == "rho":
         assert set(sch["retire_reasons"]) <= {"rho_exhausted",
                                               "stream_exhausted"}

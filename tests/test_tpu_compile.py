@@ -107,3 +107,31 @@ def test_stage1_k_compiles_for_v5e(spec, no_cache):
     # a 16 GiB chip also holds the ~2.2 GiB index and stage 2's ~5.3 GiB
     # of temporaries: stage 1's own must stay a few accumulators
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_sched_finalize_rho_compiles_without_dense_stage2(spec, no_cache):
+    """The scheduler's finalize at the benchmark cell's widths (a group
+    of 8 of 64 slots, 16 query terms x 4,096 score postings, 4,420,912
+    docs, depth 100): stage 2 runs over the gathered postings, so what it
+    adds to the temporaries of the pool selection before it stays below
+    the (8, n_docs, 3) f32 block a dense stage 2 builds."""
+    import functools
+    from repro.retrieval import topk as topk_lib
+    slots, grain, n_terms, n_docs = 64, 8, 16, 4_420_912
+    body = functools.partial(
+        engine_lib._sched_finalize_rho, depth=100, n_docs=n_docs, cap=P,
+        route="pallas", interpret=False)
+    acc, slot_idx = spec((slots, n_docs), jnp.float32), spec((grain,),
+                                                             jnp.int32)
+    args = (acc, spec((slots, n_terms * P), jnp.int32),
+            spec((slots, n_terms * P, 3), jnp.float32), slot_idx,
+            spec((grain,), jnp.int32), spec((grain,), jnp.int32),
+            spec((n_docs,), jnp.int32))
+    compiled = jax.jit(body).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    select = jax.jit(lambda a, i: topk_lib.select_pool(
+        a[i], 100, route="pallas", interpret=False)).lower(
+            acc, slot_idx).compile()
+    stage2_temp = (compiled.memory_analysis().temp_size_in_bytes
+                   - select.memory_analysis().temp_size_in_bytes)
+    assert stage2_temp < grain * n_docs * 3 * 4
