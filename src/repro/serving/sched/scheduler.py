@@ -123,6 +123,12 @@ class ContinuousScheduler:
             r: obs.metrics.counter("sched.retired." + r)
             for r in ("rho_exhausted", "stream_exhausted",
                       "pool_complete")}
+        # per finalized request, registered on either knob so that a 0
+        # says the scheduler ran: its predicted k (k knob only) and the
+        # chunk ticks its slot ran
+        self._m_finalized = obs.metrics.counter("sched.finalized")
+        self._m_k_width = obs.metrics.counter("sched.k_width")
+        self._m_chunk_ticks = obs.metrics.counter("sched.chunk_ticks")
 
     # -------------------------------------------------------------- tick --
     def tick(self, now: float | None = None) -> int:
@@ -191,7 +197,11 @@ class ContinuousScheduler:
                         if self.knob == "k" else self.full_depth)
                 self.n_rows_scored += min(s.depth, full)
                 self.n_rows_full += full
+                self._m_chunk_ticks.inc(s.chunks)
+                if self.knob == "k":
+                    self._m_k_width.inc(s.width)
                 self.table.release(s)
+            self._m_finalized.inc(len(g))
             self.n_finalize_calls += 1
         return len(g)
 

@@ -10,6 +10,7 @@ import pytest
 
 from repro.analysis import sanitizers as S
 from repro.core import experiment as E
+from repro.obs import Observability
 from repro.online.telemetry import TelemetryBuffer
 from repro.serving import pipeline as serve_lib
 from repro.serving.service import ContinuousBackend, RetrievalService
@@ -97,6 +98,74 @@ def test_churn_bit_identity_every_bucket(small_system, knob):
                                               "stream_exhausted"}
     else:
         assert set(sch["retire_reasons"]) == {"pool_complete"}
+
+
+@pytest.fixture(scope="module")
+def wide_system():
+    """A stream cap of 512 and k cutoffs up to 512: pools wider than the
+    Pallas top-k's 128 per block, as at the k deployment's 10,000."""
+    return E.build_system(E.ExperimentConfig(
+        n_docs=2000, vocab=900, n_queries=40, stream_cap=512,
+        pool_depth=512, gold_depth=50, query_batch=16, seed=23))
+
+
+def _streams(sys_, qt):
+    """Per query, its stream's length and the documents of positive
+    stage-1 score in it (the most a pool can hold)."""
+    from repro.retrieval import jass
+    idx = sys_.index
+    ds, im = jass.gather_streams(idx.offsets, idx.postings_doc,
+                                 idx.postings_impact.astype(np.float32),
+                                 qt, cap=sys_.cfg.stream_cap)
+    slen, live = [], []
+    for d, m in zip(np.asarray(ds), np.asarray(im)):
+        score = np.zeros(sys_.cfg.n_docs)
+        np.add.at(score, d[d >= 0], m[d >= 0])
+        slen.append(int((d >= 0).sum()))
+        live.append(int((score > 0).sum()))
+    return np.asarray(slen), np.asarray(live)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla", "kernel"])
+def test_wide_k_pool_churn_bit_identity(wide_system, use_kernel):
+    """The k knob with a stream cap at or above its largest cutoff and
+    cutoffs past 128: under churn at mixed predicted classes, every
+    served list is bit-identical to the per-bucket reference, including
+    queries whose pool holds more than 128 live documents (the "xla"
+    pool-select route that pools wider than the Pallas top-k take)."""
+    sys_ = wide_system
+    server, _ = _server(sys_, "k", use_kernel=use_kernel)
+    assert sys_.cfg.stream_cap >= max(server.cfg.cutoffs) > 128
+    # the base queries plus rows of the longest postings lists
+    qt = sys_.queries.terms[:32]
+    top = np.argsort(-np.diff(sys_.index.offsets), kind="stable")[:12]
+    wide = np.full((6, qt.shape[1]), -1, qt.dtype)
+    for i in range(6):
+        wide[i, :2] = top[2 * i: 2 * i + 2]
+    qt = np.concatenate([qt, wide])
+    classes = np.asarray(server.predict_classes(qt))
+    widths = np.asarray(server.params_of(classes))
+    slen, live = _streams(sys_, qt)
+    assert len(set(widths.tolist())) > 3              # mixed classes
+    assert (np.minimum(widths, live) > 128).any()
+    ref = server.serve_batch_reference(qt)["ranked"]
+
+    obs = Observability.create(capacity=4096)
+    backend = ContinuousBackend(server, slots=16, grain=4, window=8)
+    out = RetrievalService(backend, obs=obs).serve_all(list(qt),
+                                                       deadline_ms=1e6)
+    assert server.engine.topk_routes["finalize"] == "xla"
+    chunk_p = backend.scheduler.prog.chunk_p
+    for i, res in enumerate(out):
+        np.testing.assert_array_equal(res["ranked"], ref[i])
+        assert res["width"] == widths[i]
+        # k cuts no stage-1 work: every slot runs its whole stream
+        assert res["chunks_executed"] == math.ceil(slen[i] / chunk_p)
+    c = obs.metrics.counters()
+    assert c["sched.finalized"] == len(qt)
+    assert c["sched.k_width"] == int(widths.sum())
+    assert c["sched.chunk_ticks"] == sum(r["chunks_executed"] for r in out)
 
 
 @pytest.mark.parametrize("n", [1, 7])

@@ -135,3 +135,58 @@ def test_sched_finalize_rho_compiles_without_dense_stage2(spec, no_cache):
     stage2_temp = (compiled.memory_analysis().temp_size_in_bytes
                    - select.memory_analysis().temp_size_in_bytes)
     assert stage2_temp < grain * n_docs * 3 * 4
+
+
+#: the k deployment's widths (``bench/configs/msmarco-k.json``): 64 slots,
+#: refill groups of 8, 16 query terms, stream cap 16,384, 4,420,912 docs
+K_SLOTS, K_GRAIN, K_TERMS, K_CAP, K_DOCS = 64, 8, 16, 16_384, 4_420_912
+
+
+def test_sched_finalize_k_fits_beside_the_deployment(spec, no_cache):
+    """The k knob's finalize at the deployment's widths: a group of 8
+    rows, ``L·cap`` 262,144 score postings a row, the 10,000-wide pool
+    select on the "xla" route over all 4,420,912 docs, depth 100.  A
+    served run of this deployment peaks at 11.4-14.7 GB of the v5e's
+    16.9 GB with this program's ~0.57 GB of temporaries inside it, so
+    temporaries beyond 2 GiB would not fit beside the rest."""
+    import functools
+    body = functools.partial(
+        engine_lib._sched_finalize_k, depth=100, max_k=10_000,
+        n_docs=K_DOCS, cap=K_CAP, route="xla", interpret=False)
+    lp = K_TERMS * K_CAP
+    vec = spec((K_GRAIN,), jnp.int32)
+    args = (spec((K_SLOTS, K_DOCS), jnp.float32),
+            spec((K_SLOTS, lp), jnp.int32),
+            spec((K_SLOTS, lp, 3), jnp.float32), vec, vec, vec, vec,
+            spec((K_DOCS,), jnp.int32))
+    compiled = jax.jit(body).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_sched_refill_gathers_compile_at_the_k_stream_cap(spec, no_cache):
+    """The refill's stream gathers and slot install at stream cap 16,384:
+    ``(8, 16 × 16,384)`` = 2,097,152 score postings a group.  A served
+    run of this deployment peaks at 11.4-14.7 GB of the v5e's 16.9 GB,
+    so temporaries beyond 1 GiB would not fit beside the rest."""
+    import functools
+    # postings of the order of the 4,420,912-passage shard's index
+    nnz, vocab, bounds_p = 150_000_000, 2_660_824, BLOCK_P
+    gather = functools.partial(engine_lib._sched_gather, cap=K_CAP,
+                               bounds_p=bounds_p, n_docs=K_DOCS,
+                               with_bounds=True)
+    g = jax.jit(gather).lower(
+        spec((vocab + 1,), jnp.int32), spec((nnz,), jnp.int32),
+        spec((nnz,), jnp.float32), spec((nnz, 3), jnp.float32),
+        spec((K_GRAIN, K_TERMS), jnp.int32)).compile()
+    lp, nb = K_TERMS * K_CAP, K_CAP // bounds_p
+
+    def table(n):
+        return (spec((n, K_CAP), jnp.int32), spec((n, K_CAP), jnp.float32),
+                spec((n, nb), jnp.int32), spec((n, nb), jnp.int32),
+                spec((n, lp), jnp.int32), spec((n, lp, 3), jnp.float32))
+
+    r = jax.jit(engine_lib._sched_refill).lower(
+        *table(K_SLOTS), spec((K_SLOTS, K_DOCS), jnp.float32),
+        spec((K_GRAIN,), jnp.int32), *table(K_GRAIN)).compile()
+    for compiled in (g, r):
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
