@@ -20,7 +20,7 @@ from repro.kernels.topk.kernel import KP_MAX
 from repro.retrieval import jass
 
 __all__ = ["candidates_topk", "exhaustive_scores", "pool_route",
-           "select_pool"]
+           "select_pool", "select_pool_from_stream"]
 
 
 def exhaustive_scores(doc_stream, impact_stream, n_docs: int) -> jnp.ndarray:
@@ -54,7 +54,10 @@ def select_pool(scores: jnp.ndarray, depth: int, *, route: str,
     (``kernels/topk``) or the lexsort of ``rank_from_scores``.
 
     Both paths break ties toward the lower doc id, so kernel and oracle
-    select identical pools.
+    select identical pools.  It reads every one of the N columns: the
+    batch-once stage 1 selects here, while the scheduler's finalize, whose
+    rows can be positive only at their stream's documents, selects with
+    ``select_pool_from_stream``.
     """
     if route == "pallas":
         from repro.kernels.topk import ops as tk_ops
@@ -63,3 +66,42 @@ def select_pool(scores: jnp.ndarray, depth: int, *, route: str,
     if route != "xla":
         raise ValueError(f"unknown pool route {route!r}")
     return jass.rank_from_scores(scores, depth)
+
+
+def select_pool_from_stream(acc: jnp.ndarray, slot_idx: jnp.ndarray,
+                            ds_rows: jnp.ndarray, width: int, *,
+                            route: str, interpret: bool) -> jnp.ndarray:
+    """``select_pool(acc[slot_idx], width, ...)`` bit for bit, read from the
+    rows' stream documents alone: (G, width) doc ids, -1 where the score
+    is not positive.
+
+    ``acc`` (S, N) holds the slot table's accumulators and ``ds_rows``
+    (G, cap) the doc stream of each selected slot (-1 padded); a row must
+    be zero outside its stream's documents, as the scheduler's rows are
+    (refill zeroes them, chunks add only at stream documents).  Indices
+    of ``slot_idx`` past the table clamp to its last row, as in
+    ``acc[slot_idx]``, and the caller gathers ``ds_rows`` the same way.
+
+    Each row's stream is sorted by doc id (padding last), a doc listed
+    under several terms keeps its first entry, and repeats and padding
+    score 0, so only the (G, max(cap, width)) candidates go through
+    ``select_pool``.  They sit in ascending doc order, so its ties toward
+    the lower position are ties toward the lower doc id, and the
+    candidates that are not positive come out -1 as the documents
+    outside the stream do in the dense select.
+    """
+    n_docs = acc.shape[-1]
+    docs = jnp.sort(jnp.where(ds_rows >= 0, ds_rows, n_docs), axis=-1)
+    first = jnp.concatenate(
+        [jnp.ones_like(docs[:, :1], bool), docs[:, 1:] != docs[:, :-1]],
+        axis=1) & (docs < n_docs)
+    vals = jnp.where(
+        first, acc[slot_idx[:, None], jnp.minimum(docs, n_docs - 1)], 0.0)
+    pad = width - docs.shape[-1]
+    if pad > 0:
+        vals = jnp.pad(vals, ((0, 0), (0, pad)))
+        docs = jnp.pad(docs, ((0, 0), (0, pad)), constant_values=n_docs)
+    pos = select_pool(vals, width, route=route, interpret=interpret)
+    return jnp.where(
+        pos >= 0, jnp.take_along_axis(docs, jnp.maximum(pos, 0), axis=1),
+        -1)

@@ -252,30 +252,32 @@ def _sched_chunk(ds_b, im_b, lo_b, hi_b, acc, pos, end, *, chunk_p: int,
     return acc + inc
 
 
-def _sched_finalize_rho(acc, sd_b, s3_b, slot_idx, dvec, qids, doc_len, *,
-                        depth: int, n_docs: int, cap: int, route: str,
-                        interpret: bool):
+def _sched_finalize_rho(acc, ds_b, sd_b, s3_b, slot_idx, dvec, qids,
+                        doc_len, *, depth: int, n_docs: int, cap: int,
+                        route: str, interpret: bool):
     """Stages 1b-3 for a retiring group: pool selection over the finished
-    accumulator rows, then stage-2 + rerank exactly as the batch path
-    (qids are the request's arrival index, so stage-2 noise matches).
+    accumulator rows at their slots' stream documents (a top-k over at
+    most ``stream_cap`` candidates a row, not over ``n_docs`` columns),
+    then stage-2 + rerank exactly as the batch path (qids are the
+    request's arrival index, so stage-2 noise matches).
 
     ``dvec`` is the traced per-slot reranking depth; a scheduler without
     a depth knob passes the static pool width, making the mask a no-op
     (bit-identical to the depth-free program, same executable count)."""
-    rows = acc[slot_idx]
-    pool = topk_lib.select_pool(rows, depth, route=route,
-                                interpret=interpret)
+    pool = topk_lib.select_pool_from_stream(acc, slot_idx, ds_b[slot_idx],
+                                            depth, route=route,
+                                            interpret=interpret)
     scores = gold.pool_stage2_scores(sd_b[slot_idx], s3_b[slot_idx], pool,
                                      doc_len, qids, n_docs=n_docs, cap=cap)
     return gold.rerank_scored(scores, _depth_mask(pool, dvec), depth)
 
 
-def _sched_finalize_k(acc, sd_b, s3_b, slot_idx, k_vec, dvec, qids,
+def _sched_finalize_k(acc, ds_b, sd_b, s3_b, slot_idx, k_vec, dvec, qids,
                       doc_len, *, depth: int, max_k: int, n_docs: int,
                       cap: int, route: str, interpret: bool):
-    rows = acc[slot_idx]
-    pool = topk_lib.select_pool(rows, max_k, route=route,
-                                interpret=interpret)
+    pool = topk_lib.select_pool_from_stream(acc, slot_idx, ds_b[slot_idx],
+                                            max_k, route=route,
+                                            interpret=interpret)
     keep = jnp.arange(pool.shape[-1])[None, :] < k_vec[:, None]
     pool = jnp.where(keep, pool, -1)
     scores = gold.pool_stage2_scores(sd_b[slot_idx], s3_b[slot_idx], pool,
@@ -1190,6 +1192,11 @@ class SchedPrograms:
                         state.seg_lo, state.seg_hi, state.acc, pos, end)
         return dataclasses.replace(state, acc=acc)
 
+    def _finalize_table(self, state: SchedState) -> tuple:
+        """The slot-table buffers finalize reads: the accumulators, the
+        doc streams its pool select reads them at, the score streams."""
+        return state.acc, state.ds, state.sdocs, state.s3
+
     def finalize(self, state: SchedState, slot_idx: np.ndarray,
                  pvec: np.ndarray, dvec: np.ndarray,
                  qids: np.ndarray) -> np.ndarray:
@@ -1200,14 +1207,13 @@ class SchedPrograms:
         the static pool width when no depth knob is live — a no-op mask,
         bit-identical to the depth-free program)."""
         e = self.engine
+        table = self._finalize_table(state)
         if e.cfg.knob == "rho":
-            r = self._run("finalize", self._final_fn, state.acc,
-                          state.sdocs, state.s3, slot_idx, dvec, qids,
-                          e.doc_len)
+            r = self._run("finalize", self._final_fn, *table, slot_idx,
+                          dvec, qids, e.doc_len)
         else:
-            r = self._run("finalize", self._final_fn, state.acc,
-                          state.sdocs, state.s3, slot_idx, pvec, dvec,
-                          qids, e.doc_len)
+            r = self._run("finalize", self._final_fn, *table, slot_idx,
+                          pvec, dvec, qids, e.doc_len)
         with e.trace.span("sched.sync", prog="finalize"):
             r = np.asarray(r)
         return _pad_ranked(r, e.cfg.rerank_depth)
@@ -1402,6 +1408,10 @@ class ShardedSchedPrograms(SchedPrograms):
 
     def _slot_cap(self, engine: ServingEngine) -> int:
         return engine.shard_cap
+
+    def _finalize_table(self, state: SchedState) -> tuple:
+        # each shard selects over its dense accumulator rows, then merges
+        return state.acc, state.sdocs, state.s3
 
     def lend_col(self, width: int) -> int:
         """meta column (minus the 2-column prefix) of the local-end bound

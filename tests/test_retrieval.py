@@ -10,6 +10,7 @@ import pytest
 from repro.core import features as feat_lib
 from repro.retrieval import corpus as corpus_lib
 from repro.retrieval import gold, index as index_lib, jass, scoring, topk
+from repro.serving import engine as engine_lib
 
 
 @pytest.fixture(scope="module")
@@ -275,6 +276,98 @@ def test_pool_stage2_equals_dense(case):
     np.testing.assert_array_equal(
         np.asarray(gold.rerank_scored(got, pool, 30)),
         np.asarray(gold.rerank_pool(full, pool, 30)))
+
+
+def _slot_table(r, *, slots, cap, n_docs, span, pad, ties):
+    """A slot table as the scheduler leaves it: each slot's doc stream
+    (docs drawn with repeats from the first ``span`` ids, as a doc listed
+    under several terms recurs in the merged stream; -1 padded at the end
+    with ``pad``; slot 0 empty) and its accumulator row, the sum of
+    the stream's integer impacts up to a budget, zero at every other doc.
+    ``ties`` draws impacts from {1, 2, 3}, so equal sums are common."""
+    ds = np.full((slots, cap), -1, np.int32)
+    acc = np.zeros((slots, n_docs), np.float32)
+    for s in range(1, slots):
+        n = cap - (r.integers(0, cap // 2) if pad else 0)
+        ds[s, :n] = r.choice(span, n)
+        im = r.integers(1, 4 if ties else 256, n).astype(np.float32)
+        end = r.integers(n // 2, n + 1)             # the budget
+        np.add.at(acc[s], ds[s, :end], im[:end])
+    return ds, acc
+
+
+@pytest.mark.parametrize("body", ["select", "rho", "k"])
+@pytest.mark.parametrize("case", [
+    dict(route="xla", width=24, cap=64, span=40, pad=False, ties=True),
+    dict(route="pallas", width=24, cap=64, span=40, pad=True, ties=True),
+    dict(route="xla", width=200, cap=64, span=300, pad=True, ties=False),
+    dict(route="pallas", width=100, cap=64, span=300, pad=True,
+         ties=False),
+    dict(route="xla", width=40, cap=256, span=600, pad=True, ties=True),
+], ids=["xla_cut_ties", "pallas_cut_ties", "xla_cap_below_width",
+        "pallas_cap_below_width", "xla_sparse"])
+def test_stream_pool_select_equals_dense(case, body):
+    """The pool select over a slot's stream documents gives the dense
+    ``select_pool(acc[slot_idx])`` bit for bit, and both knobs' finalize
+    bodies give the lists of the dense select: docs repeated in the
+    stream, equal sums straddling the cut (the lower doc id wins), fewer
+    positive docs than ``width`` (a -1 tail), a stream narrower than the
+    pool, -1 padding, an empty stream, and a padded group entry
+    (== capacity) on both routes."""
+    r = np.random.default_rng(17)
+    slots, grain, n_docs, n_terms = 12, 8, 700, 3
+    width, cap, route = case["width"], case["cap"], case["route"]
+    ds, acc = _slot_table(r, slots=slots, cap=cap, n_docs=n_docs,
+                          span=case["span"], pad=case["pad"],
+                          ties=case["ties"])
+    slot_idx = np.concatenate(
+        [r.choice(np.arange(1, slots), grain - 2, replace=False),
+         [0, slots]]).astype(np.int32)     # empty, padding (clamps)
+    rows = acc[np.minimum(slot_idx, slots - 1)]
+    top = -np.sort(-rows, axis=1)
+    if case["ties"] and width < cap:
+        assert ((top[:, width - 1] == top[:, width])
+                & (top[:, width] > 0)).any()        # a tie at the cut
+    if width > cap:
+        assert ((top > 0).sum(axis=1) < width).all()
+    acc_d, ds_d, idx_d = map(jnp.asarray, (acc, ds, slot_idx))
+    dense = topk.select_pool(acc_d[idx_d], width, route=route,
+                             interpret=True)
+    if body == "select":
+        got = topk.select_pool_from_stream(acc_d, idx_d, ds_d[idx_d], width,
+                                           route=route, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
+        assert (np.asarray(got)[-2] == -1).all()    # the empty stream
+        return
+    sdocs, s3 = _score_postings(r, q=slots, n_terms=n_terms, cap=cap,
+                                n_docs=n_docs, span=n_docs, pad=True,
+                                lm_neg=False)
+    doc_len = jnp.asarray(r.integers(5, 300, n_docs).astype(np.int32))
+    qids = jnp.arange(50, 50 + grain, dtype=jnp.int32)
+    dvec = jnp.asarray(r.integers(1, 30, grain).astype(np.int32))
+    depth = width if body == "rho" else 30
+    pool = dense
+    if body == "k":
+        k_vec = jnp.asarray(r.integers(1, width + 1, grain).astype(np.int32))
+        pool = jnp.where(jnp.arange(width)[None, :] < k_vec[:, None],
+                         dense, -1)
+    scores = gold.pool_stage2_scores(
+        jnp.asarray(sdocs)[idx_d], jnp.asarray(s3)[idx_d], pool, doc_len,
+        qids, n_docs=n_docs, cap=cap)
+    want = gold.rerank_scored(scores, engine_lib._depth_mask(pool, dvec),
+                              depth)
+    common = dict(depth=depth, n_docs=n_docs, cap=cap, route=route,
+                  interpret=True)
+    tables = (acc_d, ds_d, jnp.asarray(sdocs), jnp.asarray(s3), idx_d)
+    if body == "rho":
+        got = jax.jit(functools.partial(engine_lib._sched_finalize_rho,
+                                        **common))(
+            *tables, dvec, qids, doc_len)
+    else:
+        got = jax.jit(functools.partial(engine_lib._sched_finalize_k,
+                                        max_k=width, **common))(
+            *tables, k_vec, dvec, qids, doc_len)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_second_stage_mix_shared_columns_equal_per_row():

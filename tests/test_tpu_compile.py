@@ -10,6 +10,7 @@ worker collects the same tests and only the one that runs them loads the
 TPU compiler."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,12 +110,26 @@ def test_stage1_k_compiles_for_v5e(spec, no_cache):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def _widest_sort_or_kernel(compiled) -> int:
+    """The widest dimension of any operand or result of a sort or a
+    Pallas call in a compiled program's text."""
+    widest = 0
+    for line in compiled.as_text().splitlines():
+        if " sort(" in line or "tpu_custom_call" in line:
+            dims = re.findall(r"\[([\d,]+)\]", line)
+            widest = max([widest] + [int(d) for g in dims
+                                     for d in g.split(",") if d])
+    return widest
+
+
 def test_sched_finalize_rho_compiles_without_dense_stage2(spec, no_cache):
     """The scheduler's finalize at the benchmark cell's widths (a group
     of 8 of 64 slots, 16 query terms x 4,096 score postings, 4,420,912
-    docs, depth 100): stage 2 runs over the gathered postings, so what it
-    adds to the temporaries of the pool selection before it stays below
-    the (8, n_docs, 3) f32 block a dense stage 2 builds."""
+    docs, depth 100): the pool select reads the slots' 4,096 stream docs,
+    so no sort or Pallas call spans the docs, and stage 2 runs over the
+    gathered postings, so what it adds to the temporaries of the pool
+    selection stays below the (8, n_docs, 3) f32 block a dense stage 2
+    builds."""
     import functools
     from repro.retrieval import topk as topk_lib
     slots, grain, n_terms, n_docs = 64, 8, 16, 4_420_912
@@ -123,15 +138,17 @@ def test_sched_finalize_rho_compiles_without_dense_stage2(spec, no_cache):
         route="pallas", interpret=False)
     acc, slot_idx = spec((slots, n_docs), jnp.float32), spec((grain,),
                                                              jnp.int32)
-    args = (acc, spec((slots, n_terms * P), jnp.int32),
+    ds = spec((slots, P), jnp.int32)
+    args = (acc, ds, spec((slots, n_terms * P), jnp.int32),
             spec((slots, n_terms * P, 3), jnp.float32), slot_idx,
             spec((grain,), jnp.int32), spec((grain,), jnp.int32),
             spec((n_docs,), jnp.int32))
     compiled = jax.jit(body).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    select = jax.jit(lambda a, i: topk_lib.select_pool(
-        a[i], 100, route="pallas", interpret=False)).lower(
-            acc, slot_idx).compile()
+    assert _widest_sort_or_kernel(compiled) < n_docs
+    select = jax.jit(lambda a, d, i: topk_lib.select_pool_from_stream(
+        a, i, d[i], 100, route="pallas", interpret=False)).lower(
+            acc, ds, slot_idx).compile()
     stage2_temp = (compiled.memory_analysis().temp_size_in_bytes
                    - select.memory_analysis().temp_size_in_bytes)
     assert stage2_temp < grain * n_docs * 3 * 4
@@ -145,10 +162,11 @@ K_SLOTS, K_GRAIN, K_TERMS, K_CAP, K_DOCS = 64, 8, 16, 16_384, 4_420_912
 def test_sched_finalize_k_fits_beside_the_deployment(spec, no_cache):
     """The k knob's finalize at the deployment's widths: a group of 8
     rows, ``L·cap`` 262,144 score postings a row, the 10,000-wide pool
-    select on the "xla" route over all 4,420,912 docs, depth 100.  A
-    served run of this deployment peaks at 11.4-14.7 GB of the v5e's
-    16.9 GB with this program's ~0.57 GB of temporaries inside it, so
-    temporaries beyond 2 GiB would not fit beside the rest."""
+    select on the "xla" route over the slots' 16,384 stream docs (no
+    sort spans the 4,420,912 docs), depth 100.  A served run of this
+    deployment peaks at 11.4-14.7 GB of the v5e's 16.9 GB.  The
+    program's temporaries compile to ~0.16 GB, most of them stage 2's
+    (8, 262,144) key sort; a select over the docs held ~0.57 GB."""
     import functools
     body = functools.partial(
         engine_lib._sched_finalize_k, depth=100, max_k=10_000,
@@ -156,11 +174,13 @@ def test_sched_finalize_k_fits_beside_the_deployment(spec, no_cache):
     lp = K_TERMS * K_CAP
     vec = spec((K_GRAIN,), jnp.int32)
     args = (spec((K_SLOTS, K_DOCS), jnp.float32),
+            spec((K_SLOTS, K_CAP), jnp.int32),
             spec((K_SLOTS, lp), jnp.int32),
             spec((K_SLOTS, lp, 3), jnp.float32), vec, vec, vec, vec,
             spec((K_DOCS,), jnp.int32))
     compiled = jax.jit(body).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    assert _widest_sort_or_kernel(compiled) < K_DOCS
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
 def test_sched_refill_gathers_compile_at_the_k_stream_cap(spec, no_cache):
